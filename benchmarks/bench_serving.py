@@ -300,9 +300,9 @@ def kernel_row(quick: bool = False):
     rng = np.random.default_rng(0)
     n_pool = b * maxp
     q = jnp.asarray(rng.standard_normal((b, kv, g, hd)), jnp.float32)
-    k_pages = jnp.asarray(rng.standard_normal((n_pool, pt, kv, hd)),
+    k_pages = jnp.asarray(rng.standard_normal((n_pool, kv, pt, hd)),
                           jnp.float32)
-    v_pages = jnp.asarray(rng.standard_normal((n_pool, pt, kv, hd)),
+    v_pages = jnp.asarray(rng.standard_normal((n_pool, kv, pt, hd)),
                           jnp.float32)
     tables = jnp.asarray(rng.permutation(n_pool).reshape(b, maxp), jnp.int32)
     positions = jnp.asarray(rng.integers(0, maxp * pt, size=b), jnp.int32)
@@ -324,7 +324,7 @@ def kernel_row(quick: bool = False):
     rec = {"shape": {"batch": b, "kv_heads": kv, "group": g, "head_dim": hd,
                      "page_tokens": pt, "pages_per_req": maxp},
            "kernel_us": kus, "ref_us": rus, "max_abs_err": err,
-           "interpret": kops._interpret_default()}
+           "interpret": kops.interpret_requested()}
     derived = (f"kernel_us={kus:.1f};ref_us={rus:.1f};"
                f"err={err:.2e};interpret={rec['interpret']}")
     return (f"kernel/paged_attention/b{b}", kus, derived), rec

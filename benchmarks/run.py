@@ -87,6 +87,8 @@ def main() -> None:
     quick = args.quick or bool(int(os.environ.get("BENCH_QUICK", "0")))
     (bench_alloc_time, bench_heuristic, bench_memory, bench_remat,
      bench_reopt, bench_serving, bench_unified, scenarios) = _import_benches()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sections = [
         ("fig2", bench_memory.main),
         ("fig3", bench_alloc_time.main),

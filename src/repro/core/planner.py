@@ -18,16 +18,11 @@ from .dsa import AllocationPlan, plan_quality, validate_plan
 from .events import MemoryProfile
 from .exact import solve_exact
 from .liveness import profile_fn
+from .peaks import attached_peaks
 from .pool import NaiveAllocator, PoolAllocator, replay
 from .reorder import ReorderResult, reorder_profile
 from .solvers import SolverUnavailable, have_solver, solve_milp
 
-# TPU v5e physical budgets (DESIGN.md §8.2).
-VMEM_BYTES = 16 * 1024 * 1024          # ~16 MiB per core
-HBM_BYTES = 16 * 1024 ** 3             # 16 GiB per chip
-PEAK_FLOPS_BF16 = 197e12               # per chip
-HBM_BW = 819e9                         # bytes/s
-ICI_BW = 50e9                          # bytes/s/link
 
 _SOLVERS: dict[str, Callable[[MemoryProfile], AllocationPlan]] = {
     "bestfit": best_fit,
@@ -122,19 +117,25 @@ class MemoryPlanner:
 
     @classmethod
     def check_vmem(cls, block_shapes, buffering: int = 2,
-                   budget: int = VMEM_BYTES) -> dict:
+                   budget: int | None = None) -> dict:
+        """``budget`` defaults to the attached chip's VMEM."""
+        if budget is None:
+            budget = attached_peaks().vmem_bytes
         used = cls.vmem_footprint(block_shapes, buffering)
         return {"bytes": used, "budget": budget, "fits": used <= budget,
                 "utilization": used / budget}
 
     def max_feasible_batch(self, bytes_at_batch: Callable[[int], int],
-                           hbm_budget: int = HBM_BYTES,
+                           hbm_budget: int | None = None,
                            lo: int = 1, hi: int = 65536) -> int:
-        """Largest batch whose planned per-device peak fits the HBM budget.
+        """Largest batch whose planned per-device peak fits the HBM budget
+        (default: the attached chip's HBM).
 
         ``bytes_at_batch(b)`` must be monotone in ``b`` (it typically wraps a
         profile-and-plan of the step at mini-batch ``b``).
         """
+        if hbm_budget is None:
+            hbm_budget = attached_peaks().hbm_bytes
         if bytes_at_batch(lo) > hbm_budget:
             return 0
         while lo < hi:
@@ -210,7 +211,7 @@ class MemoryPlanner:
 
     def max_feasible_batch_planned(self,
                                    profile_at_batch: Callable[[int], MemoryProfile],
-                                   hbm_budget: int = HBM_BYTES,
+                                   hbm_budget: int | None = None,
                                    lo: int = 1, hi: int = 65536, *,
                                    remat=None) -> int:
         """Remat-aware ``max_feasible_batch`` over actual profiles.
@@ -224,6 +225,8 @@ class MemoryPlanner:
         recompute/offload sets can actually evict; ``True`` / mode "full"
         searches unconstrained.
         """
+        if hbm_budget is None:
+            hbm_budget = attached_peaks().hbm_bytes
         use_remat = bool(remat) and getattr(remat, "mode", "x") != "none"
         cand_filter = None
         if use_remat:

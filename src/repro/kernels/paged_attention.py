@@ -15,8 +15,12 @@ bounds validity (``k_pos <= pos[b]``), which also makes partial last pages
 and the zero-padded tail of short page-table rows exact — padded entries
 point at page 0, whose keys fall outside every row's valid range.
 
-Layout: q (B, KV, G, hd); k/v pools (P, page_tokens, KV, hd);
+Layout: q (B, KV, G, hd); k/v pools (P, KV, page_tokens, hd);
 tables (B, n_pages_per_req) int32; positions (B,) int32 -> out (B, KV, G, hd).
+The pool keeps page_tokens and hd as its two minor axes so that one (head,
+page) block is a (page_tokens, hd) tile: the TPU lowering requires a block's
+last two dims to be multiples of (8, 128) or the full array dims, which a
+KV axis cut to 1 in the second-minor position breaks.
 """
 from __future__ import annotations
 
@@ -29,6 +33,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# f32 contractions at full precision: Mosaic's default may round f32 operands
+# to bf16, which would put the probabilities through a bf16 rounding that
+# attention.attend_decode (the gather path) does not make
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
@@ -48,8 +56,9 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     @pl.when(i * page_tokens <= pos)
     def _page():
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)             # (pt, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                # (pt, hd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=HIGHEST,
                                 preferred_element_type=jnp.float32)  # (G, pt)
         g = s.shape[0]
         k_pos = i * page_tokens + jax.lax.broadcasted_iota(
@@ -61,9 +70,10 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[:, None])
         corr = jnp.exp(m_prev - m_new)
-        v = v_ref[0, :, 0].astype(jnp.float32)             # (pt, hd)
+        v = v_ref[0, 0].astype(jnp.float32)                # (pt, hd)
         acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((1,), (0,)), ((), ())), precision=HIGHEST,
+            preferred_element_type=jnp.float32)
         m_scr[...] = m_new
         l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1)
 
@@ -75,12 +85,12 @@ def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
 def paged_attention_decode(q, k_pages, v_pages, tables, positions, *,
                            interpret=False):
-    """q: (B, KV, G, hd); k/v pools: (P, pt, KV, hd);
+    """q: (B, KV, G, hd); k/v pools: (P, KV, pt, hd);
     tables: (B, maxp) int32 page ids (pad unused entries with any in-bounds
     id — masking keeps them inert); positions: (B,) int32, row b attends to
     token indices <= positions[b].  Returns (B, KV, G, hd)."""
     b, kv, g, hd = q.shape
-    p, pt, kv_k, hd_k = k_pages.shape
+    p, kv_k, pt, hd_k = k_pages.shape
     assert (kv_k, hd_k) == (kv, hd), (k_pages.shape, q.shape)
     assert v_pages.shape == k_pages.shape
     maxp = tables.shape[1]
@@ -95,10 +105,10 @@ def paged_attention_decode(q, k_pages, v_pages, tables, positions, *,
         in_specs=[
             pl.BlockSpec((1, 1, g, hd),
                          lambda bi, hi, i, tbl, pos: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, pt, 1, hd),
-                         lambda bi, hi, i, tbl, pos: (tbl[bi, i], 0, hi, 0)),
-            pl.BlockSpec((1, pt, 1, hd),
-                         lambda bi, hi, i, tbl, pos: (tbl[bi, i], 0, hi, 0)),
+            pl.BlockSpec((1, 1, pt, hd),
+                         lambda bi, hi, i, tbl, pos: (tbl[bi, i], hi, 0, 0)),
+            pl.BlockSpec((1, 1, pt, hd),
+                         lambda bi, hi, i, tbl, pos: (tbl[bi, i], hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd),
                                lambda bi, hi, i, tbl, pos: (bi, hi, 0, 0)),

@@ -30,20 +30,24 @@ def ref_attention_bhsd(q, k, v, *, causal=True, window=0, q_offset=0):
 def ref_paged_attention(q, k_pages, v_pages, tables, positions):
     """Gather-then-softmax oracle for the paged decode kernel.
 
-    q: (B,KV,G,hd); k/v pools: (P,pt,KV,hd); tables: (B,maxp) int32;
+    q: (B,KV,G,hd); k/v pools: (P,KV,pt,hd); tables: (B,maxp) int32;
     positions: (B,) — row b attends to token indices <= positions[b].
-    Token t of row b lives at (tables[b, t // pt], t % pt)."""
+    Token t of row b, head h lives at (tables[b, t // pt], h, t % pt)."""
     b, kv, g, hd = q.shape
-    pt = k_pages.shape[1]
+    pt = k_pages.shape[2]
     maxp = tables.shape[1]
-    k = k_pages[tables].reshape(b, maxp * pt, kv, hd).astype(jnp.float32)
-    v = v_pages[tables].reshape(b, maxp * pt, kv, hd).astype(jnp.float32)
-    s = jnp.einsum("bkgh,bskh->bkgs", q.astype(jnp.float32), k) / jnp.sqrt(hd)
+
+    def gather(pages):              # (B,maxp,KV,pt,hd) -> (B,KV,maxp*pt,hd)
+        return pages[tables].transpose(0, 2, 1, 3, 4).reshape(
+            b, kv, maxp * pt, hd).astype(jnp.float32)
+
+    k, v = gather(k_pages), gather(v_pages)
+    s = jnp.einsum("bkgh,bksh->bkgs", q.astype(jnp.float32), k) / jnp.sqrt(hd)
     idx = jnp.arange(maxp * pt)
     valid = idx[None, :] <= positions[:, None]
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bkgs,bskh->bkgh", p, v).astype(q.dtype)
+    return jnp.einsum("bkgs,bksh->bkgh", p, v).astype(q.dtype)
 
 
 def ref_ssd(x, dta, b_mat, c_mat, h0=None):

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import ARCHS, SHAPES, get_config
+from ..core.peaks import attached_peaks
 from ..models import RunOpts, Transformer
 from ..optim.adamw import AdamWConfig
 from ..runtime import serve_lib, train_lib
@@ -67,7 +68,8 @@ def lower_cell(arch: str, shape_name: str, mesh, args):
     model = Transformer(cfg, opts)
     kind = shape.kind
     meta = {"arch": arch, "shape": shape_name, "kind": kind,
-            "mesh": dict(zip(mesh.axis_names, mesh.devices.shape))}
+            "mesh": dict(zip(mesh.axis_names, mesh.devices.shape)),
+            "device_kind": attached_peaks().device_kind}
 
     if kind == "train":
         acfg = AdamWConfig()

@@ -7,8 +7,11 @@ Per (arch x shape x mesh) cell:
 plus MODEL_FLOPS = analytic useful flops (6*N_active*D for training), and the
 MODEL/HLO ratio that exposes remat & replication waste.
 
-Hardware constants (TPU v5e): 197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link
-ICI (one link assumed per transfer — conservative, uniform across cells).
+Hardware constants: the row of ``core/peaks.py`` for the chip the cell was
+compiled on (``meta["device_kind"]``; artifacts without it are dry runs for
+the planning target, TPU v5e: 197 bf16 TFLOP/s, 819 GB/s HBM, 50 GB/s per
+ICI link — one link assumed per transfer, conservative and uniform across
+cells).
 """
 from __future__ import annotations
 
@@ -19,10 +22,7 @@ from dataclasses import dataclass
 
 from ..configs import SHAPES, get_config
 from ..configs.base import ModelConfig, ShapeConfig
-
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+from ..core.peaks import PLANNING_TARGET, ChipPeaks, peaks_for
 
 
 # ---------------------------------------------------------------------------
@@ -142,11 +142,12 @@ class Cell:
     hlo_flops_global: float
     useful_ratio: float
     raw: dict
+    peaks: ChipPeaks
 
     @property
     def ideal_s(self) -> float:
         """Per-device time if only MODEL_FLOPS ran at peak."""
-        return self.model_flops / (self.chips * PEAK_FLOPS)
+        return self.model_flops / (self.chips * self.peaks.bf16_flops)
 
     @property
     def step_bound_s(self) -> float:
@@ -166,9 +167,10 @@ def analyze_cell_json(meta: dict) -> Cell:
     for v in meta["mesh"].values():
         chips *= v
     h = meta["hlo"]
-    compute_s = h["dot_flops"] / PEAK_FLOPS
-    memory_s = h["hbm_bytes"] / HBM_BW
-    coll_s = h["coll_bytes"] / ICI_BW
+    row = peaks_for(meta.get("device_kind", PLANNING_TARGET))
+    compute_s = h["dot_flops"] / row.bf16_flops
+    memory_s = h["hbm_bytes"] / row.hbm_bw
+    coll_s = h["coll_bytes"] / row.ici_bw
     dominant = max((("compute", compute_s), ("memory", memory_s),
                     ("collective", coll_s)), key=lambda t: t[1])[0]
     mf = model_flops(cfg, shape)["model_flops"]
@@ -177,7 +179,8 @@ def analyze_cell_json(meta: dict) -> Cell:
         arch=meta["arch"], shape=meta["shape"], mesh=meta["mesh_tag"],
         chips=chips, compute_s=compute_s, memory_s=memory_s, coll_s=coll_s,
         dominant=dominant, model_flops=mf, hlo_flops_global=hlo_global,
-        useful_ratio=mf / hlo_global if hlo_global else 0.0, raw=meta)
+        useful_ratio=mf / hlo_global if hlo_global else 0.0, raw=meta,
+        peaks=row)
 
 
 def load_cells(dirpath: str, mesh: str | None = "single") -> list:

@@ -9,6 +9,10 @@ slab-per-request baseline).
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --requests 8
 
+``--preset full`` runs the published config unchanged (its dtype, every
+layer, the full vocabulary) with the weights held in that dtype; the other
+presets are the reduced float32 configs of ``launch.train.reduced_config``.
+
 ``--share-hbm GB``: one budget, two workloads — a fine-tune step of the same
 (reduced) model is registered as the training tenant of a ``SharedArena``,
 the page pool becomes the serving tenant, and admission is gated against the
@@ -35,10 +39,11 @@ from ..configs import get_config
 from ..core import MemoryPlanner, SharedArena, profile_fn
 from ..models import Transformer
 from ..obs import (ChromeTraceBuilder, DriftMonitor, SLOEngine, SLOSpec,
-                   SpanTracker, Tracer, use_tracer)
+                   SpanTracker, Tracer, get_tracer, use_tracer)
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.serve_lib import ServingArena, synth_trace
 from ..serving import GenRequest, ServeEngine
-from .train import reduced_config
+from .train import PRESETS, reduced_config
 
 
 def make_train_step(model, params, seq: int, batch: int, lr: float = 1e-3,
@@ -92,10 +97,15 @@ def run_interleaved(eng, live, shared, train_step, max_steps: int = 100_000):
     }
 
 
-def main() -> None:
+def main(argv=None) -> ServeEngine:
+    """Parse ``argv``, serve the trace, print the report; returns the
+    drained engine (``engine.completed`` holds every token stream)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
-    ap.add_argument("--preset", default="tiny")
+    ap.add_argument("--preset", default="tiny",
+                    choices=sorted(PRESETS) + ["full"],
+                    help="'full': the published config as is; otherwise a "
+                         "reduced float32 config")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -118,9 +128,9 @@ def main() -> None:
                     help="decode KV layout: 'gather' copies each slot's "
                          "contiguous cache rows through the runner; 'paged' "
                          "runs the Pallas paged-attention kernel straight "
-                         "off the page pool (requires --runner; on CPU set "
-                         "REPRO_PALLAS_INTERPRET=1 or rely on the automatic "
-                         "interpret-mode fallback)")
+                         "off the page pool (requires --runner; without a "
+                         "TPU set REPRO_PALLAS_INTERPRET=1 for interpret "
+                         "mode)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace", default="", metavar="PATH",
                     help="write a Chrome-trace/Perfetto JSON of the run "
@@ -134,18 +144,30 @@ def main() -> None:
                     help="per-token decode-cadence ceiling (engine steps)")
     ap.add_argument("--slo-e2e", type=float, default=None, metavar="STEPS",
                     help="enqueue->finish ceiling (engine steps)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.preset == "full" and args.share_hbm > 0:
+        ap.error("--share-hbm fine-tunes a reduced config; use a reduced "
+                 "--preset with it")
+    enable_compile_cache()
 
-    cfg, seq, batch = reduced_config(args.arch, args.preset)
+    # full-size arch for the memory accounting
+    full_cfg = get_config(args.arch)
+    if args.preset == "full":
+        cfg, seq, batch = full_cfg, None, None
+    else:
+        cfg, seq, batch = reduced_config(args.arch, args.preset)
     model = Transformer(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    # serving holds its weights in the compute dtype (a no-op for the
+    # float32 reduced presets); one jitted init compiles one program where
+    # an eager init compiles one per parameter shape
+    params = jax.jit(lambda key: jax.tree.map(
+        lambda a: a.astype(cfg.dtype), model.init(key)))(
+            jax.random.PRNGKey(args.seed))
 
     # profile run: the sample trace the planner sizes the page pool from
     trace = synth_trace(args.requests, args.prompt_len, args.gen_len,
                         seed=args.seed, jitter=False)
 
-    # full-size arch for the memory accounting; reduced model for execution
-    full_cfg = get_config(args.arch)
     acct = ServingArena(full_cfg, trace)
     cmp = acct.compare_pool()
     print(f"[{args.arch} @ full size] slab baseline for {len(trace)} requests: "
@@ -174,11 +196,10 @@ def main() -> None:
                       accounting_cfg=full_cfg, shared=shared,
                       use_runner=args.runner, attn_mode=args.attn)
     if args.runner:
-        t0 = time.perf_counter()
         eng.warmup()
         print(f"[runner] buckets={list(eng.runner.buckets)} warmed "
-              f"{eng.runner.n_compiles} compiles in "
-              f"{time.perf_counter() - t0:.1f}s")
+              f"{eng.runner.n_compiles} compiles (+{eng.prefill_compiles} "
+              f"prefill) in {eng.warmup_s:.1f}s")
     kv = eng.kv.stats()
     print(f"[paged pool] page_tokens={kv['page_tokens']} "
           f"n_pages={kv['n_pages']} pool={kv['pool_bytes'] / 1e6:.2f}MB "
@@ -206,7 +227,8 @@ def main() -> None:
             for r in trace]
     want_slo = any(v is not None
                    for v in (args.slo_ttft, args.slo_tpot, args.slo_e2e))
-    tracer = Tracer() if (args.trace or want_slo) else None
+    # a caller's active tracer keeps the events when none is asked for here
+    tracer = Tracer() if (args.trace or want_slo) else get_tracer()
     colocated = None
     with use_tracer(tracer):
         if shared is not None:
@@ -285,6 +307,7 @@ def main() -> None:
               f"feasible={shared.plan().feasible} "
               f"reserves={{'serving': {shared.plan().reserves['serving'] / 1e6:.1f}MB, "
               f"'training': {shared.plan().reserves['training'] / 1e6:.1f}MB}}")
+    return eng
 
 
 if __name__ == "__main__":

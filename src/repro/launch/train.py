@@ -29,6 +29,7 @@ from ..data import DataConfig, SyntheticPipeline
 from ..models import RunOpts, Transformer
 from ..optim.adamw import AdamWConfig
 from ..runtime import train_lib
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.fault import SimulatedFailure, StragglerMonitor, TrainController
 
 PRESETS = {
@@ -101,6 +102,7 @@ def main() -> None:
     ap.add_argument("--metrics", action="store_true",
                     help="print planner metrics as Prometheus text")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = Tracer() if args.trace else None
     if tracer is not None:

@@ -178,10 +178,15 @@ def attend_decode(q, k_cache, v_cache, cache_pos, *, window=0, rolling=False):
     ``rolling=True`` means the cache is a circular window buffer (local
     attention at long context); validity is then positional-age based and
     already guaranteed by construction, so only the fill mask applies.
+
+    Scores, probabilities and the context stay in float32 until the context
+    is cast back to ``q.dtype`` — the numerics of the paged decode kernel, so
+    the gather and paged paths differ only in f32 rounding, in bf16 too.
     """
     hd = q.shape[-1]
     scale = hd ** -0.5
-    s = jnp.einsum("bqkgh,bskh->bkgqs", q, k_cache).astype(jnp.float32) * scale
+    s = jnp.einsum("bqkgh,bskh->bkgqs", q, k_cache,
+                   preferred_element_type=jnp.float32) * scale
     c = k_cache.shape[1]
     idx = jnp.arange(c)
     pos = jnp.asarray(cache_pos).reshape(-1, 1)         # (B,1) or (1,1)
@@ -192,17 +197,19 @@ def attend_decode(q, k_cache, v_cache, cache_pos, *, window=0, rolling=False):
         if window:
             valid &= idx[None, :] > (pos - window)
     s = s + jnp.where(valid[:, None, None, None, :], 0.0, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bkgqs,bskh->bqkgh", p, v_cache)
+    p = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bkgqs,bskh->bqkgh", p, v_cache.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return ctx.astype(q.dtype)
 
 
 def attend_paged_decode(q, k_pages, v_pages, tables, cache_pos, *,
                         impl="pallas"):
     """Decode attention straight off the paged pool — no gather, no copy.
 
-    q: (B,1,kv,g,hd); k/v pools: (P,pt,kv,hd) shared by the whole batch;
-    tables: (B,maxp) int32 page-index rows (token t of row b lives at
-    (tables[b, t//pt], t%pt)); cache_pos: (B,) per-slot positions — row b
+    q: (B,1,kv,g,hd); k/v pools: (P,kv,pt,hd) shared by the whole batch;
+    tables: (B,maxp) int32 page-index rows (token t of row b, head h lives
+    at (tables[b, t//pt], h, t%pt)); cache_pos: (B,) per-slot positions — row b
     attends to token indices <= cache_pos[b].
 
     ``impl="pallas"`` runs the Pallas kernel (the page table drives the

@@ -360,6 +360,13 @@ class Transformer:
                          cdt(table, self.compute_dtype))
         return mesh_ctx.shard(out, "batch", "seq", "vocab")
 
+    def greedy(self, logits):
+        """Greedy next-token ids from (..., padded_vocab) logits.  The
+        padding rows of the vocabulary are never picked: they are weights
+        like any other, and their logits can win."""
+        return jnp.argmax(logits[..., :self.cfg.vocab_size],
+                          axis=-1).astype(jnp.int32)
+
     def _ce(self, logits, targets, mask):
         cfg = self.cfg
         lf = logits.astype(jnp.float32)
@@ -507,7 +514,7 @@ class Transformer:
             f"paged cache unsupported for pattern {cfg.block_pattern}"
         g = cfg.n_pattern_groups
         kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-        pool = jax.ShapeDtypeStruct((g, n_pages, page_tokens, kv, hd), dt)
+        pool = jax.ShapeDtypeStruct((g, n_pages, kv, page_tokens, hd), dt)
         return {
             "pos": jax.ShapeDtypeStruct((batch,), jnp.int32),
             "block_tables": jax.ShapeDtypeStruct((batch, pages_per_req),
@@ -578,8 +585,8 @@ class Transformer:
 
     def _apply_block_decode_paged(self, x, p, cache, pos, tables, rope_cs):
         """One attn block against the paged pool.  cache: {"k_pages",
-        "v_pages"} (P,pt,kv,hd); tables: (B,maxp) page-index rows; pos: (B,).
-        The new token's KV is scattered to (tables[b, pos//pt], pos%pt) and
+        "v_pages"} (P,kv,pt,hd); tables: (B,maxp) page-index rows; pos: (B,).
+        The new token's KV is scattered to (tables[b, pos//pt], :, pos%pt) and
         attention reads the pool through the table — no gathered copy of the
         request's KV ever materializes."""
         cfg, dt = self.cfg, self.compute_dtype
@@ -589,13 +596,13 @@ class Transformer:
             q = attn.apply_rope(q, *rope_cs)
             k = attn.apply_rope(k, *rope_cs)
         k_pages, v_pages = cache["k_pages"], cache["v_pages"]
-        pt = k_pages.shape[1]
+        pt = k_pages.shape[2]
         page = jnp.take_along_axis(tables, (pos // pt)[:, None], axis=1)[:, 0]
         off = pos % pt
         # duplicate (page, off) pairs from runner slot-padding write
         # identical values, so the scatter is order-independent
-        k_pages = k_pages.at[page, off].set(k[:, 0])
-        v_pages = v_pages.at[page, off].set(v[:, 0])
+        k_pages = k_pages.at[page, :, off].set(k[:, 0])     # (B,kv,hd) rows
+        v_pages = v_pages.at[page, :, off].set(v[:, 0])
         ctx = attn.attend_paged_decode(q, k_pages, v_pages, tables, pos,
                                        impl=self.opts.paged_attn_impl)
         x = x + attn.out_project(ctx, p["attn"], cfg, dt)

@@ -28,7 +28,11 @@ from dataclasses import dataclass
 from typing import Optional
 
 from ..core.events import Block, MemoryProfile
-from ..core.planner import PEAK_FLOPS_BF16 as PEAK_FLOPS  # one hardware model
+from ..core.peaks import PLANNING_TARGET, peaks_for
+
+# datasheet bf16 peak of the planning target (core/peaks.py): the pricing
+# default when no measured step time calibrates it
+PEAK_FLOPS = peaks_for(PLANNING_TARGET).bf16_flops
 
 HOST_LINK_BW = 50e9          # bytes/s, device<->host staging (PCIe-class)
 

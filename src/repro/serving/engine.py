@@ -121,6 +121,7 @@ class ServeEngine:
         self.decode_compiles = 0
         self.decode_steps = 0
         self.decode_time_s = 0.0
+        self.warmup_s = 0.0
         if attn_mode not in ("gather", "paged"):
             raise ValueError(f"unknown attn_mode {attn_mode!r}")
         self.attn_mode = attn_mode
@@ -173,7 +174,9 @@ class ServeEngine:
     def warmup(self) -> None:
         """Pre-compile every runner bucket *and* every prefill ladder shape
         so the serving loop never traces (the zero-retrace invariant holds
-        from step 0 for decode and prefill alike)."""
+        from step 0 for decode and prefill alike).  ``warmup_s`` records the
+        wall time, compilation included."""
+        t0 = time.perf_counter()
         if self.runner is not None:
             self.runner.warmup(self.params, self.cache, self.tokens)
         if self._pad_prefill:
@@ -186,6 +189,8 @@ class ServeEngine:
                 if p >= self.max_len:
                     break
                 padded *= 2
+        jax.block_until_ready(self.cache)
+        self.warmup_s = time.perf_counter() - t0
 
     # -- queue --------------------------------------------------------------------
     def enqueue(self, req: GenRequest) -> None:
@@ -277,7 +282,7 @@ class ServeEngine:
         # async writes would otherwise be absorbed into the next decode
         # step's sync and pollute the measured decode step time
         jax.block_until_ready(self.cache)
-        tok = jnp.argmax(logits[0]).astype(jnp.int32)
+        tok = self.model.greedy(logits[0])
         self.tokens = self.tokens.at[sr.slot].set(tok)
         if not self._grow(sr):          # prefill already yields one token
             return
@@ -294,7 +299,8 @@ class ServeEngine:
         t = get_tracer()
         if t is not None:
             t.instant("decode", "serving", track="engine",
-                      n_running=len(running))
+                      n_running=len(running),
+                      slots=[sr.slot for sr in running])
         t0 = time.perf_counter()
         if self.runner is not None:
             slots = [sr.slot for sr in running]
@@ -307,8 +313,7 @@ class ServeEngine:
         else:
             logits, self.cache = self.decode(self.params, self.cache,
                                              self.tokens)
-            nxt = jnp.argmax(jax.block_until_ready(logits),
-                             axis=-1).astype(jnp.int32)
+            nxt = self.model.greedy(jax.block_until_ready(logits))
             self.tokens = nxt
             by_slot = None
         self.decode_time_s += time.perf_counter() - t0
@@ -341,7 +346,7 @@ class ServeEngine:
         new["block_tables"] = cache["block_tables"].at[sr.slot].set(table_row)
         want = n_rowp * ept
 
-        def cut(x):                 # (G,1,S,kv,hd) -> (G,n_rowp,ept,kv,hd)
+        def cut(x):                 # (G,1,S,kv,hd) -> (G,n_rowp,kv,ept,hd)
             x = x[:, 0]
             s = x.shape[1]
             if s < want:
@@ -349,7 +354,8 @@ class ServeEngine:
                             (x.ndim - 2))
             elif s > want:          # ladder padding past the granted pages
                 x = x[:, :want]
-            return x.reshape(x.shape[0], n_rowp, ept, *x.shape[2:])
+            x = x.reshape(x.shape[0], n_rowp, ept, *x.shape[2:])
+            return x.transpose(0, 1, 3, 2, 4)
 
         pat = {}
         for i, entry in cache["pattern"].items():

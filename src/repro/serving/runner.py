@@ -136,7 +136,7 @@ class DecodeRunner:
         # executable: the engine's hot loop then never runs eager per-shape
         # ops (an eager argmax/scatter would quietly compile once per batch
         # size, off the runner's compile counter)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        nxt = self.model.greedy(logits)
         new_tokens = tokens.at[slots].set(nxt)
         return logits, nxt, new_tokens, _scatter_rows(cache, new_sub, slots)
 
@@ -171,6 +171,10 @@ class DecodeRunner:
                 jax.ShapeDtypeStruct((bucket,), jnp.int32))
             c = self._compiled[bucket] = lowered.compile()
         return c
+
+    def executable(self, bucket: int) -> jax.stages.Compiled:
+        """The compiled step of an already compiled ``bucket``."""
+        return self._compiled[bucket]
 
     def warmup(self, params, cache, tokens) -> int:
         """Compile every bucket up front *and* replay each one end to end

@@ -8,6 +8,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import Transformer
 from repro.obs.metrics import MetricsRegistry, use_registry
+from repro.obs.trace import Tracer, use_tracer
 from repro.runtime.serve_lib import Request
 from repro.serving import DecodeRunner, GenRequest, ServeEngine, bucket_ladder
 
@@ -165,13 +166,16 @@ def _run_mode(model, params, trace, live, attn_mode):
     eng = ServeEngine(model, params, sample_trace=trace, max_len=64,
                       max_batch=4, page_tokens=8, attn_mode=attn_mode)
     reg = MetricsRegistry()
-    with use_registry(reg):
+    with use_registry(reg), use_tracer(Tracer()) as tracer:
         eng.warmup()
         warm_runner = eng.runner.n_compiles
         warm_prefill = eng.prefill_compiles
         summary = eng.run(live)
     assert eng.runner.n_compiles == warm_runner     # zero decode retraces
     assert eng.prefill_compiles == warm_prefill     # zero prefill retraces
+    summary["decode_slots"] = [(ev.step, ev.args["slots"])
+                               for ev in tracer.events()
+                               if ev.name == "decode"]
     return eng, summary
 
 
@@ -188,6 +192,12 @@ def test_paged_token_parity_under_preemption_churn(tiny_model):
     assert s_p["n_preemptions"] == s_g["n_preemptions"] > 0  # genuine churn
     assert paged.completed == gather.completed      # token-exact, every rid
     assert paged.step_count >= 100                  # sustained churn window
+    # the decode trace names each step's slots, and both engines run the
+    # same schedule
+    assert s_p["decode_slots"] == s_g["decode_slots"]
+    assert len(s_g["decode_slots"]) == gather.decode_steps
+    assert all(0 < len(sl) <= 4 and sl == sorted(set(sl)) and
+               set(sl) <= set(range(4)) for _, sl in s_g["decode_slots"])
 
 
 def test_paged_staggered_admissions_match_isolated_decode(tiny_model):

@@ -110,3 +110,16 @@ def test_vmem_budget_guard():
     k = jnp.ones((1, 2048, 1, 2048), jnp.float32)
     with pytest.raises(AssertionError, match="VMEM"):
         ops.flash_attention(q, k, k, block_q=2048, block_k=2048)
+
+
+@pytest.mark.parametrize("value,want", [
+    (None, False), ("", False), ("0", False), ("false", False),
+    ("1", True), ("yes", True)])
+def test_interpret_mode_only_on_request(monkeypatch, value, want):
+    """No backend sniffing: interpret mode comes from the environment (or an
+    explicit argument), never from finding no chip."""
+    if value is None:
+        monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", value)
+    assert ops.interpret_requested() is want

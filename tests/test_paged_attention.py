@@ -55,8 +55,8 @@ def _case(seed, b, kv, g, hd, pt, maxp, *, dtype=jnp.float32,
         tables[i, :need] = order[used:used + need]
         used += need
     q = jnp.asarray(rng.standard_normal((b, kv, g, hd)), dtype)
-    k_pages = jnp.asarray(rng.standard_normal((n_pool, pt, kv, hd)), dtype)
-    v_pages = jnp.asarray(rng.standard_normal((n_pool, pt, kv, hd)), dtype)
+    k_pages = jnp.asarray(rng.standard_normal((n_pool, kv, pt, hd)), dtype)
+    v_pages = jnp.asarray(rng.standard_normal((n_pool, kv, pt, hd)), dtype)
     return q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(positions)
 
 
@@ -64,11 +64,15 @@ def _contiguous(q, k_pages, v_pages, tables, positions):
     """Gather-execution baseline: pages copied into a contiguous cache, then
     the engine's contiguous decode attention."""
     b, kv, g, hd = q.shape
-    pt = k_pages.shape[1]
+    pt = k_pages.shape[2]
     maxp = tables.shape[1]
-    k = k_pages[tables].reshape(b, maxp * pt, kv, hd)
-    v = v_pages[tables].reshape(b, maxp * pt, kv, hd)
-    return attend_decode(q[:, None], k, v, positions)[:, 0]
+
+    def gather(pages):              # (B,maxp,kv,pt,hd) -> (B,maxp*pt,kv,hd)
+        return pages[tables].transpose(0, 1, 3, 2, 4).reshape(
+            b, maxp * pt, kv, hd)
+
+    return attend_decode(q[:, None], gather(k_pages), gather(v_pages),
+                         positions)[:, 0]
 
 
 def _check(q, k_pages, v_pages, tables, positions, tol):

@@ -4,7 +4,7 @@ import pytest
 
 from repro.core import MemoryPlanner, make_profile, to_lp
 from repro.core.mip import num_variables
-from repro.core.planner import HBM_BYTES, VMEM_BYTES
+from repro.core.peaks import attached_peaks
 
 
 def test_report_contains_baseline_comparison():
@@ -41,10 +41,12 @@ def test_max_feasible_batch_monotone():
     def bytes_at(b):
         return fixed + b * per_sample
 
+    hbm = attached_peaks().hbm_bytes
     mp = MemoryPlanner()
-    b = mp.max_feasible_batch(bytes_at, hbm_budget=HBM_BYTES)
-    assert bytes_at(b) <= HBM_BYTES < bytes_at(b + 1)
-    assert mp.max_feasible_batch(lambda b: HBM_BYTES * 2, HBM_BYTES) == 0
+    b = mp.max_feasible_batch(bytes_at, hbm_budget=hbm)
+    assert bytes_at(b) <= hbm < bytes_at(b + 1)
+    assert mp.max_feasible_batch(bytes_at) == b     # default: the chip's HBM
+    assert mp.max_feasible_batch(lambda b: hbm * 2, hbm) == 0
 
 
 def test_max_feasible_batch_monotone_in_budget():
@@ -121,3 +123,39 @@ def test_lp_export_structure():
     # every colliding pair yields two no-overlap rows
     assert lp.count("no_ov_a") == nv["z"]
     assert lp.count("no_ov_b") == nv["z"]
+
+
+def test_peaks_table_keyed_by_device_kind(monkeypatch):
+    import jax
+
+    from repro.core.peaks import PLANNING_TARGET, peaks_for
+    from repro.launch import roofline
+    row = peaks_for(PLANNING_TARGET)
+    assert row.device_kind == PLANNING_TARGET and row.source
+    # a CPU host plans for the planning target
+    assert attached_peaks() == row
+    assert MemoryPlanner.check_vmem([((8, 128), np.dtype("float32"))])[
+        "budget"] == row.vmem_bytes
+    meta = {"arch": "qwen2-0.5b", "shape": "train_4k", "mesh_tag": "single",
+            "mesh": {"data": 1}, "device_kind": row.device_kind,
+            "hlo": {"dot_flops": 1e14, "hbm_bytes": 1e13, "coll_bytes": 1e11}}
+    cell = roofline.analyze_cell_json(meta)
+    assert cell.peaks == row
+    assert cell.memory_s == pytest.approx(1e13 / row.hbm_bw)
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks_for("cpu")
+
+    class Chip:                                 # an attached accelerator
+        platform = "tpu"
+        device_kind = PLANNING_TARGET
+    monkeypatch.setattr(jax, "devices", lambda *a: [Chip()])
+    assert attached_peaks() == row
+    # one the table does not know raises through the planner's budgets and
+    # the roofline, never falls back to the planning target
+    Chip.device_kind = "TPU v99"
+    with pytest.raises(KeyError, match="TPU v99"):
+        MemoryPlanner.check_vmem([((8, 128), np.dtype("float32"))])
+    with pytest.raises(KeyError, match="TPU v99"):
+        MemoryPlanner().max_feasible_batch(lambda b: b)
+    with pytest.raises(KeyError, match="TPU v99"):
+        roofline.analyze_cell_json(dict(meta, device_kind="TPU v99"))

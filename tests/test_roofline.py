@@ -65,7 +65,7 @@ def test_cell_analysis_roundtrip():
     cell = roofline.analyze_cell_json(meta)
     assert cell.chips == 256
     assert cell.dominant == "memory"
-    assert cell.compute_s == pytest.approx(1e14 / roofline.PEAK_FLOPS)
+    assert cell.compute_s == pytest.approx(1e14 / cell.peaks.bf16_flops)
     assert 0 < cell.fraction < 1
     assert cell.step_bound_s == cell.memory_s
 

@@ -84,3 +84,28 @@ def test_engine_generates_greedy_reference(rng_key):
     # lazy relocation shim still resolves for old call sites
     from repro.runtime import serve_lib
     assert serve_lib.ServeEngine is ServeEngine
+
+
+@pytest.mark.parametrize("attn_mode", ["gather", "paged"])
+def test_greedy_never_picks_vocab_padding(rng_key, attn_mode):
+    """The vocabulary is padded (qwen2-0.5b: 151,936 -> 152,064); the pad
+    rows are weights like any other, and greedy serving must not emit them."""
+    cfg = get_config("qwen2-0.5b").smoke().with_overrides(vocab_size=300)
+    assert cfg.padded_vocab > cfg.vocab_size
+    model = Transformer(cfg)
+    params = model.init(rng_key)
+    table = "lm_head" if "lm_head" in params else "embed"
+    # pad rows that outscore every real row: an ungated argmax picks them
+    params[table] = params[table].at[cfg.vocab_size:].multiply(1e3)
+    prompt = jax.random.randint(jax.random.PRNGKey(5), (6,), 0, cfg.vocab_size)
+    toks, out_ref = list(prompt), []
+    for _ in range(5):
+        logits = model.forward(params, jnp.asarray(toks)[None, :])[0, -1]
+        assert int(jnp.argmax(logits)) >= cfg.vocab_size    # trap is armed
+        out_ref.append(int(jnp.argmax(logits[:cfg.vocab_size])))
+        toks.append(out_ref[-1])
+
+    eng = ServeEngine(model, params, max_batch=2, max_len=16,
+                      sample_trace=[Request(1, 6, 5, 0)], attn_mode=attn_mode)
+    eng.run([GenRequest(rid=1, prompt=prompt, gen_len=5)])
+    assert eng.completed[1] == out_ref

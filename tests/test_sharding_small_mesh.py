@@ -87,8 +87,10 @@ print("RESULT " + json.dumps(out))
 @pytest.fixture(scope="module")
 def result():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
-    env["PYTHONPATH"] = os.path.join(repo, "src")
+    # the child wants 8 fake host devices and must never reach for a chip
+    # (this process may already hold it)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(repo, "src"))
     # the 8-fake-device script compiles several model families; on a loaded
     # CPU host it sits just under 9 minutes, so leave real headroom
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], capture_output=True,
