@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(run):
+    dt = run.device_trace
+    return dt["idle_pct"] if dt else None

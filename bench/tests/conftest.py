@@ -1,0 +1,11 @@
+"""The harness's own tests run on the CPU:
+``JAX_PLATFORMS=cpu python -m pytest -q bench/tests``."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+for p in (HERE, BENCH, os.path.join(os.path.dirname(BENCH), "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
