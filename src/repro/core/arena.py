@@ -28,7 +28,7 @@ from .dsa import AllocationPlan, validate_plan
 from .events import DEFAULT_ALIGNMENT, Block, MemoryProfile, align
 from .pool import PoolAllocator
 from .profiler import MemoryRecorder
-from ..obs.trace import get_tracer
+from ..obs.trace import get_tracer, span
 
 
 class ArenaAllocator:
@@ -274,32 +274,31 @@ class ArenaAllocator:
         self.reopt_seconds += _time.perf_counter() - t0
 
     def _install(self, profile: MemoryProfile, cause: str = "boundary") -> None:
-        t0 = _time.perf_counter()
-        old_peak = self.plan.peak
-        if self.incremental:
-            plan = refit(profile, self.profile, self.plan, solver=self._solver)
-        else:
-            plan = self._solver(profile)
-            plan.stats.setdefault("mode", "full")
-        validate_plan(profile, plan)
-        self.profile = profile
-        self.plan = plan
-        replan_mode = plan.stats.get("mode", "full")
-        if replan_mode == "incremental":
-            self.n_incr_replans += 1
-        else:
-            self.n_full_replans += 1
-        self._by_bid = {b.bid: b for b in profile.blocks}
-        self._lam0 = min((b.bid for b in profile.blocks), default=1)
-        self.n_reopt += 1
-        self.max_peak = max(self.max_peak, self.plan.peak)
-        self.last_replan_s = _time.perf_counter() - t0
-        t = get_tracer()
-        if t is not None:
-            t.instant("replan", "arena", track="arena", n_reopt=self.n_reopt,
-                      old_peak=old_peak, new_peak=self.plan.peak,
-                      n_blocks=profile.n, cause=cause, mode=replan_mode,
-                      seconds=self.last_replan_s)
+        with span("replan", "arena", "arena", cause=cause) as sp:
+            t0 = _time.perf_counter()
+            old_peak = self.plan.peak
+            if self.incremental:
+                plan = refit(profile, self.profile, self.plan,
+                             solver=self._solver)
+            else:
+                plan = self._solver(profile)
+                plan.stats.setdefault("mode", "full")
+            validate_plan(profile, plan)
+            self.profile = profile
+            self.plan = plan
+            replan_mode = plan.stats.get("mode", "full")
+            if replan_mode == "incremental":
+                self.n_incr_replans += 1
+            else:
+                self.n_full_replans += 1
+            self._by_bid = {b.bid: b for b in profile.blocks}
+            self._lam0 = min((b.bid for b in profile.blocks), default=1)
+            self.n_reopt += 1
+            self.max_peak = max(self.max_peak, self.plan.peak)
+            self.last_replan_s = _time.perf_counter() - t0
+            sp.note(n_reopt=self.n_reopt, old_peak=old_peak,
+                    new_peak=self.plan.peak, n_blocks=profile.n,
+                    mode=replan_mode, seconds=self.last_replan_s)
 
     def stats(self) -> dict:
         return {

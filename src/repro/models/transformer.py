@@ -210,6 +210,7 @@ class Transformer:
         return abstract_params(self.schema(), dtype="float32")
 
     # ---- shared pieces -----------------------------------------------------------
+    @jax.named_scope("embed")
     def _embed_in(self, params, tokens):
         cfg = self.cfg
         x = embed_lookup(params["embed"], tokens, self.compute_dtype)
@@ -242,27 +243,28 @@ class Transformer:
         cfg, opts, dt = self.cfg, self.opts, self.compute_dtype
         cache_out = {}
         if kind in ("attn", "local", "xattn"):
-            h = apply_norm(x, p["attn"]["norm"], cfg.norm)
-            q, k, v = attn.qkv_project(h, p["attn"], cfg, dt)
-            if rope_cs is not None:
-                q = attn.apply_rope(q, *rope_cs)
-                k = attn.apply_rope(k, *rope_cs)
-            impl = self._attn_impl(x.shape[1], training)
-            window = cfg.local_window if kind == "local" else 0
-            if opts.cp_attention:
-                # context parallelism: q's sequence over the model axis; k/v
-                # stay replicated there (gathered once — they are kv-headed
-                # and small), so the S^2 work shards even when head counts
-                # don't divide the model axis.
-                q = mesh_ctx.shard(q, "batch", "seq_cp", "kv_heads", None,
-                                   "head_dim")
-            ctx = attn.attend(q, k, v, impl=impl, causal=cfg.causal, window=window,
-                              chunk=opts.attn_chunk,
-                              softmax_dtype=jnp.dtype(opts.softmax_dtype))
-            if opts.cp_attention:
-                ctx = mesh_ctx.shard(ctx, "batch", None, "kv_heads", None,
-                                     "head_dim")
-            x = x + attn.out_project(ctx, p["attn"], cfg, dt)
+            with jax.named_scope("attn"):
+                h = apply_norm(x, p["attn"]["norm"], cfg.norm)
+                q, k, v = attn.qkv_project(h, p["attn"], cfg, dt)
+                if rope_cs is not None:
+                    q = attn.apply_rope(q, *rope_cs)
+                    k = attn.apply_rope(k, *rope_cs)
+                impl = self._attn_impl(x.shape[1], training)
+                window = cfg.local_window if kind == "local" else 0
+                if opts.cp_attention:
+                    # context parallelism: q's sequence over the model
+                    # axis; k/v stay replicated there (gathered once — they
+                    # are kv-headed and small), so the S^2 work shards even
+                    # when head counts don't divide the model axis.
+                    q = mesh_ctx.shard(q, "batch", "seq_cp", "kv_heads", None,
+                                       "head_dim")
+                ctx = attn.attend(q, k, v, impl=impl, causal=cfg.causal,
+                                  window=window, chunk=opts.attn_chunk,
+                                  softmax_dtype=jnp.dtype(opts.softmax_dtype))
+                if opts.cp_attention:
+                    ctx = mesh_ctx.shard(ctx, "batch", None, "kv_heads", None,
+                                         "head_dim")
+                x = x + attn.out_project(ctx, p["attn"], cfg, dt)
             if want_cache:
                 cache_out["self"] = {"k": k, "v": v}
             if kind == "xattn":
@@ -274,15 +276,16 @@ class Transformer:
                 x = x + attn.out_project(ctx, p["xattn"], cfg, dt)
                 if want_cache:
                     cache_out["cross"] = {"k": kx, "v": vx}
-            h = apply_norm(x, p["mlp_norm"], cfg.norm)
-            if cfg.n_experts:
-                y, aux = moe_lib.moe_mlp(h, p["mlp"], cfg, dt,
-                                         grouped=opts.moe_grouped)
-                x = x + y
-                cache_out["aux"] = aux
-            else:
-                from .layers import mlp as dense_mlp
-                x = x + dense_mlp(h, p["mlp"], cfg.act, dt)
+            with jax.named_scope("mlp"):
+                h = apply_norm(x, p["mlp_norm"], cfg.norm)
+                if cfg.n_experts:
+                    y, aux = moe_lib.moe_mlp(h, p["mlp"], cfg, dt,
+                                             grouped=opts.moe_grouped)
+                    x = x + y
+                    cache_out["aux"] = aux
+                else:
+                    from .layers import mlp as dense_mlp
+                    x = x + dense_mlp(h, p["mlp"], cfg.act, dt)
         elif kind == "rec":
             h = apply_norm(x, p["norm"], cfg.norm)
             x = x + rglru_lib.recurrent_block(h, p, cfg, dt,
@@ -353,6 +356,7 @@ class Transformer:
     def _lm_table(self, params):
         return params.get("lm_head", params["embed"])
 
+    @jax.named_scope("head")
     def logits(self, params, x):
         cfg = self.cfg
         table = self._lm_table(params)
@@ -539,21 +543,24 @@ class Transformer:
         cfg, dt = self.cfg, self.compute_dtype
         new_cache = dict(cache)
         if kind in ("attn", "local", "xattn"):
-            h = apply_norm(x, p["attn"]["norm"], cfg.norm)
-            q, k, v = attn.qkv_project(h, p["attn"], cfg, dt)
-            if rope_cs is not None:
-                q = attn.apply_rope(q, *rope_cs)
-                k = attn.apply_rope(k, *rope_cs)
-            c = cache["k"].shape[1]
-            slot = jnp.mod(pos, c) if kind == "local" else jnp.minimum(pos, c - 1)
-            rows = jnp.arange(k.shape[0])
-            k_cache = cache["k"].at[rows, slot].set(k[:, 0])
-            v_cache = cache["v"].at[rows, slot].set(v[:, 0])
-            new_cache["k"], new_cache["v"] = k_cache, v_cache
-            window = cfg.local_window if kind == "local" else 0
-            ctx = attn.attend_decode(q, k_cache, v_cache, pos, window=window,
-                                     rolling=(kind == "local"))
-            x = x + attn.out_project(ctx, p["attn"], cfg, dt)
+            with jax.named_scope("attn"):
+                h = apply_norm(x, p["attn"]["norm"], cfg.norm)
+                q, k, v = attn.qkv_project(h, p["attn"], cfg, dt)
+                if rope_cs is not None:
+                    q = attn.apply_rope(q, *rope_cs)
+                    k = attn.apply_rope(k, *rope_cs)
+                c = cache["k"].shape[1]
+                slot = (jnp.mod(pos, c) if kind == "local"
+                        else jnp.minimum(pos, c - 1))
+                rows = jnp.arange(k.shape[0])
+                k_cache = cache["k"].at[rows, slot].set(k[:, 0])
+                v_cache = cache["v"].at[rows, slot].set(v[:, 0])
+                new_cache["k"], new_cache["v"] = k_cache, v_cache
+                window = cfg.local_window if kind == "local" else 0
+                ctx = attn.attend_decode(q, k_cache, v_cache, pos,
+                                         window=window,
+                                         rolling=(kind == "local"))
+                x = x + attn.out_project(ctx, p["attn"], cfg, dt)
             if kind == "xattn":
                 h = apply_norm(x, p["xnorm"], cfg.norm)
                 qx, _, _ = attn.qkv_project(h, p["xattn"], cfg, dt)
@@ -561,14 +568,15 @@ class Transformer:
                 ctx = attn.attend_decode(qx, cache["xk"], cache["xv"],
                                          jnp.asarray(enc_len - 1, jnp.int32))
                 x = x + attn.out_project(ctx, p["xattn"], cfg, dt)
-            h = apply_norm(x, p["mlp_norm"], cfg.norm)
-            if cfg.n_experts:
-                y, _ = moe_lib.moe_mlp(h, p["mlp"], cfg, dt,
-                                       grouped=self.opts.moe_grouped)
-                x = x + y
-            else:
-                from .layers import mlp as dense_mlp
-                x = x + dense_mlp(h, p["mlp"], cfg.act, dt)
+            with jax.named_scope("mlp"):
+                h = apply_norm(x, p["mlp_norm"], cfg.norm)
+                if cfg.n_experts:
+                    y, _ = moe_lib.moe_mlp(h, p["mlp"], cfg, dt,
+                                           grouped=self.opts.moe_grouped)
+                    x = x + y
+                else:
+                    from .layers import mlp as dense_mlp
+                    x = x + dense_mlp(h, p["mlp"], cfg.act, dt)
             return x, new_cache
         if kind == "rec":
             h = apply_norm(x, p["norm"], cfg.norm)
@@ -590,30 +598,33 @@ class Transformer:
         attention reads the pool through the table — no gathered copy of the
         request's KV ever materializes."""
         cfg, dt = self.cfg, self.compute_dtype
-        h = apply_norm(x, p["attn"]["norm"], cfg.norm)
-        q, k, v = attn.qkv_project(h, p["attn"], cfg, dt)
-        if rope_cs is not None:
-            q = attn.apply_rope(q, *rope_cs)
-            k = attn.apply_rope(k, *rope_cs)
-        k_pages, v_pages = cache["k_pages"], cache["v_pages"]
-        pt = k_pages.shape[2]
-        page = jnp.take_along_axis(tables, (pos // pt)[:, None], axis=1)[:, 0]
-        off = pos % pt
-        # duplicate (page, off) pairs from runner slot-padding write
-        # identical values, so the scatter is order-independent
-        k_pages = k_pages.at[page, :, off].set(k[:, 0])     # (B,kv,hd) rows
-        v_pages = v_pages.at[page, :, off].set(v[:, 0])
-        ctx = attn.attend_paged_decode(q, k_pages, v_pages, tables, pos,
-                                       impl=self.opts.paged_attn_impl)
-        x = x + attn.out_project(ctx, p["attn"], cfg, dt)
-        h = apply_norm(x, p["mlp_norm"], cfg.norm)
-        if cfg.n_experts:
-            y, _ = moe_lib.moe_mlp(h, p["mlp"], cfg, dt,
-                                   grouped=self.opts.moe_grouped)
-            x = x + y
-        else:
-            from .layers import mlp as dense_mlp
-            x = x + dense_mlp(h, p["mlp"], cfg.act, dt)
+        with jax.named_scope("attn"):
+            h = apply_norm(x, p["attn"]["norm"], cfg.norm)
+            q, k, v = attn.qkv_project(h, p["attn"], cfg, dt)
+            if rope_cs is not None:
+                q = attn.apply_rope(q, *rope_cs)
+                k = attn.apply_rope(k, *rope_cs)
+            k_pages, v_pages = cache["k_pages"], cache["v_pages"]
+            pt = k_pages.shape[2]
+            page = jnp.take_along_axis(tables, (pos // pt)[:, None],
+                                       axis=1)[:, 0]
+            off = pos % pt
+            # duplicate (page, off) pairs from runner slot-padding write
+            # identical values, so the scatter is order-independent
+            k_pages = k_pages.at[page, :, off].set(k[:, 0])  # (B,kv,hd) rows
+            v_pages = v_pages.at[page, :, off].set(v[:, 0])
+            ctx = attn.attend_paged_decode(q, k_pages, v_pages, tables, pos,
+                                           impl=self.opts.paged_attn_impl)
+            x = x + attn.out_project(ctx, p["attn"], cfg, dt)
+        with jax.named_scope("mlp"):
+            h = apply_norm(x, p["mlp_norm"], cfg.norm)
+            if cfg.n_experts:
+                y, _ = moe_lib.moe_mlp(h, p["mlp"], cfg, dt,
+                                       grouped=self.opts.moe_grouped)
+                x = x + y
+            else:
+                from .layers import mlp as dense_mlp
+                x = x + dense_mlp(h, p["mlp"], cfg.act, dt)
         return x, {"k_pages": k_pages, "v_pages": v_pages}
 
     # ---- public: decode (one token for every sequence in the batch) --------------
@@ -728,16 +739,18 @@ class Transformer:
         def apply_prefill(kind, x, p):
             dt = self.compute_dtype
             if kind in ("attn", "local", "xattn"):
-                h = apply_norm(x, p["attn"]["norm"], cfg.norm)
-                q, k, v = attn.qkv_project(h, p["attn"], cfg, dt)
-                if rope_cs is not None:
-                    q = attn.apply_rope(q, *rope_cs)
-                    k = attn.apply_rope(k, *rope_cs)
-                impl = self._attn_impl(s, training=False)
-                window = cfg.local_window if kind == "local" else 0
-                ctx = attn.attend(q, k, v, impl=impl, causal=True, window=window,
-                                  chunk=self.opts.attn_chunk)
-                x = x + attn.out_project(ctx, p["attn"], cfg, dt)
+                with jax.named_scope("attn"):
+                    h = apply_norm(x, p["attn"]["norm"], cfg.norm)
+                    q, k, v = attn.qkv_project(h, p["attn"], cfg, dt)
+                    if rope_cs is not None:
+                        q = attn.apply_rope(q, *rope_cs)
+                        k = attn.apply_rope(k, *rope_cs)
+                    impl = self._attn_impl(s, training=False)
+                    window = cfg.local_window if kind == "local" else 0
+                    ctx = attn.attend(q, k, v, impl=impl, causal=True,
+                                      window=window,
+                                      chunk=self.opts.attn_chunk)
+                    x = x + attn.out_project(ctx, p["attn"], cfg, dt)
                 kc, vc = fill_kv(kind, k, v)
                 entry = {"k": kc, "v": vc}
                 if kind == "xattn":
@@ -747,14 +760,15 @@ class Transformer:
                     ctx = attn.attend(qx, kx, vx, impl="full", causal=False)
                     x = x + attn.out_project(ctx, p["xattn"], cfg, dt)
                     entry["xk"], entry["xv"] = kx, vx
-                h = apply_norm(x, p["mlp_norm"], cfg.norm)
-                if cfg.n_experts:
-                    y, _ = moe_lib.moe_mlp(h, p["mlp"], cfg, dt,
-                                           grouped=self.opts.moe_grouped)
-                    x = x + y
-                else:
-                    from .layers import mlp as dense_mlp
-                    x = x + dense_mlp(h, p["mlp"], cfg.act, dt)
+                with jax.named_scope("mlp"):
+                    h = apply_norm(x, p["mlp_norm"], cfg.norm)
+                    if cfg.n_experts:
+                        y, _ = moe_lib.moe_mlp(h, p["mlp"], cfg, dt,
+                                               grouped=self.opts.moe_grouped)
+                        x = x + y
+                    else:
+                        from .layers import mlp as dense_mlp
+                        x = x + dense_mlp(h, p["mlp"], cfg.act, dt)
                 return x, entry
             if kind == "rec":
                 h = apply_norm(x, p["norm"], cfg.norm)

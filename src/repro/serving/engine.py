@@ -30,7 +30,7 @@ from ..configs.base import ModelConfig
 from ..core.unified import SharedArena
 from ..models.transformer import Transformer
 from ..obs.metrics import get_registry
-from ..obs.trace import get_tracer
+from ..obs.trace import NO_SPAN, get_tracer, span
 from ..runtime.serve_lib import (Request, build_decode_step,
                                  build_prefill_step)
 from . import pages as pages_lib
@@ -216,26 +216,29 @@ class ServeEngine:
         t = get_tracer()
         if t is not None:
             t.set_step(self.step_count)
-        for sr in self.sched.admit(self.step_count):
-            self.metrics.on_admit(sr.rid, self.step_count)
-        for sr in self.sched.prefill_batch():
-            if sr.state is RequestState.RUNNING:    # not preempted by an
-                self._model_prefill(sr)             # earlier grow this step
-        self._decode_running()
-        self.metrics.on_step(concurrent=self.sched.n_active,
-                             occupancy=self.kv.occupancy(),
-                             queue_depth=self.sched.queue_depth)
-        self.step_count += 1
-        if self.sched.idle:
-            self.kv.reset_epoch()       # epoch boundary: §4.3 replan if dirty
-            self._refresh_cap()
-        elif (self.replan_interval
-              and self.step_count % self.replan_interval == 0):
-            # sustained load never goes idle — close the epoch on a clock so
-            # decode-outrun replans still fire (pool resize respects live
-            # pages, so this is safe mid-flight)
-            self.kv.reset_epoch()
-            self._refresh_cap()
+        with (NO_SPAN if t is None else t.span(
+                "step", "serving", "engine", step=self.step_count,
+                n_active=self.sched.n_active,
+                queue_depth=self.sched.queue_depth)):
+            for sr in self.sched.admit(self.step_count):
+                self.metrics.on_admit(sr.rid, self.step_count)
+            for sr in self.sched.prefill_batch():
+                if sr.state is RequestState.RUNNING:  # not preempted by an
+                    self._model_prefill(sr)           # earlier grow this step
+            self._decode_running()
+            self.metrics.on_step(concurrent=self.sched.n_active,
+                                 occupancy=self.kv.occupancy(),
+                                 queue_depth=self.sched.queue_depth)
+            self.step_count += 1
+            # an epoch closes when the engine goes idle (§4.3 replan if
+            # dirty) and, under sustained load that never goes idle, on a
+            # clock so decode-outrun replans still fire (pool resize
+            # respects live pages, so this is safe mid-flight)
+            if self.sched.idle or (self.replan_interval and self.step_count
+                                   % self.replan_interval == 0):
+                with span("epoch", "serving", "engine"):
+                    self.kv.reset_epoch()
+                    self._refresh_cap()
 
     def _refresh_cap(self) -> None:
         """Unified mode: a boundary replan may have rebalanced the split, so
@@ -268,25 +271,36 @@ class ServeEngine:
 
     def _model_prefill(self, sr: ScheduledRequest) -> None:
         self.metrics.n_prefill_tokens += sr.prompt_len
-        t = get_tracer()
-        if t is not None:
-            t.instant("prefill", "serving", track="engine", rid=sr.rid,
-                      prompt_len=sr.prompt_len, slot=sr.slot)
-        logits, cache1 = self.prefill(self.params,
-                                      self._prefill_batch(sr.req.prompt))
-        if self.attn_mode == "paged":
-            self.cache = self._merge_paged(self.cache, cache1, sr)
-        else:
-            self.cache = _merge_slot(self.cache, cache1, sr.slot, self.max_len)
-        # settle the merge here so its cost is attributed to prefill — the
-        # async writes would otherwise be absorbed into the next decode
-        # step's sync and pollute the measured decode step time
-        jax.block_until_ready(self.cache)
-        tok = self.model.greedy(logits[0])
-        self.tokens = self.tokens.at[sr.slot].set(tok)
+        with span("prefill", "serving", "engine", rid=sr.rid,
+                  prompt_len=sr.prompt_len, slot=sr.slot):
+            with span("prefill.pad", "serving", "engine"):
+                batch = self._prefill_batch(sr.req.prompt)
+            with span("prefill.launch", "serving", "engine"):
+                logits, cache1 = self.prefill(self.params, batch)
+            with span("prefill.merge", "serving", "engine"):
+                if self.attn_mode == "paged":
+                    self.cache = self._merge_paged(self.cache, cache1, sr)
+                else:
+                    self.cache = _merge_slot(self.cache, cache1, sr.slot,
+                                             self.max_len)
+            # settle the merge here so its cost is attributed to prefill —
+            # the async writes would otherwise be absorbed into the next
+            # decode step's sync and pollute the measured decode step time
+            with span("prefill.sync", "serving", "engine"):
+                jax.block_until_ready(self.cache)
+            with span("prefill.pick", "serving", "engine"):
+                tok = self.model.greedy(logits[0])
+                self.tokens = self.tokens.at[sr.slot].set(tok)
+                tok = int(tok)
+        # the span closes first: a preemption or finish below is a later
+        # lifecycle event than this prefill
         if not self._grow(sr):          # prefill already yields one token
             return
-        sr.out.append(int(tok))
+        sr.out.append(tok)
+        t = get_tracer()
+        if (t is not None and
+                self.metrics.requests[sr.rid].first_token_step is None):
+            t.instant("first-token", "serving", track="engine", rid=sr.rid)
         self.metrics.on_first_token(sr.rid, self.step_count)
         self.metrics.on_token(sr.rid)
         if sr.remaining <= 0:
@@ -297,37 +311,40 @@ class ServeEngine:
         if not running:
             return
         t = get_tracer()
-        if t is not None:
-            t.instant("decode", "serving", track="engine",
-                      n_running=len(running),
-                      slots=[sr.slot for sr in running])
-        t0 = time.perf_counter()
-        if self.runner is not None:
-            slots = [sr.slot for sr in running]
-            # greedy pick + token-buffer update happen inside the compiled
-            # step, so this branch is pure executable replay; nxt arrives as
-            # host ints (step_greedy blocks on the transfer)
-            nxt, self.tokens, self.cache = self.runner.step_greedy(
-                self.params, self.cache, self.tokens, slots)
-            by_slot = {slot: i for i, slot in enumerate(slots)}
-        else:
-            logits, self.cache = self.decode(self.params, self.cache,
-                                             self.tokens)
-            nxt = self.model.greedy(jax.block_until_ready(logits))
-            self.tokens = nxt
-            by_slot = None
-        self.decode_time_s += time.perf_counter() - t0
-        self.decode_steps += 1
-        for sr in running:
-            if sr.state is not RequestState.RUNNING:
-                continue                # preempted by an earlier grow this step
-            if not self._grow(sr):
-                continue                # sr itself was the preemption victim
-            tok = nxt[by_slot[sr.slot]] if by_slot is not None else nxt[sr.slot]
-            sr.out.append(int(tok))
-            self.metrics.on_token(sr.rid)
-            if sr.remaining <= 0:
-                self._finish(sr)
+        with (NO_SPAN if t is None else t.span(
+                "decode", "serving", "engine", rows=len(running),
+                bucket=(self.runner.bucket_for(len(running))
+                        if self.runner is not None else self.max_batch),
+                slots=[sr.slot for sr in running])):
+            t0 = time.perf_counter()
+            if self.runner is not None:
+                slots = [sr.slot for sr in running]
+                # greedy pick + token-buffer update happen inside the
+                # compiled step, so this branch is pure executable replay;
+                # nxt arrives as host ints (step_greedy blocks on the
+                # transfer)
+                nxt, self.tokens, self.cache = self.runner.step_greedy(
+                    self.params, self.cache, self.tokens, slots)
+                by_slot = {slot: i for i, slot in enumerate(slots)}
+            else:
+                logits, self.cache = self.decode(self.params, self.cache,
+                                                 self.tokens)
+                nxt = self.model.greedy(jax.block_until_ready(logits))
+                self.tokens = nxt
+                by_slot = None
+            self.decode_time_s += time.perf_counter() - t0
+            self.decode_steps += 1
+            for sr in running:
+                if sr.state is not RequestState.RUNNING:
+                    continue        # preempted by an earlier grow this step
+                if not self._grow(sr):
+                    continue            # sr itself was the preemption victim
+                tok = (nxt[by_slot[sr.slot]] if by_slot is not None
+                       else nxt[sr.slot])
+                sr.out.append(int(tok))
+                self.metrics.on_token(sr.rid)
+                if sr.remaining <= 0:
+                    self._finish(sr)
 
     def _merge_paged(self, cache, cache1, sr: ScheduledRequest):
         """Install one request into the paged cache: position clock, exec

@@ -29,7 +29,7 @@ from jax.sharding import Mesh
 
 from ..models.transformer import Transformer
 from ..obs.metrics import MetricsRegistry, get_registry
-from ..obs.trace import get_tracer
+from ..obs.trace import get_tracer, span
 from ..runtime import mesh_ctx
 
 
@@ -125,8 +125,9 @@ class DecodeRunner:
         self._note_compile(int(slots.shape[0]))      # trace-time only
         ctx = (mesh_ctx.use_mesh(self.mesh, rules=self.model.opts.mesh_rules())
                if self.mesh is not None else None)
-        sub = _gather_rows(cache, slots)
-        sub_tokens = jnp.take(tokens, slots)
+        with jax.named_scope("gather"):
+            sub = _gather_rows(cache, slots)
+            sub_tokens = jnp.take(tokens, slots)
         if ctx is not None:
             with ctx:
                 logits, new_sub = self.model.decode_step(params, sub, sub_tokens)
@@ -137,8 +138,10 @@ class DecodeRunner:
         # ops (an eager argmax/scatter would quietly compile once per batch
         # size, off the runner's compile counter)
         nxt = self.model.greedy(logits)
-        new_tokens = tokens.at[slots].set(nxt)
-        return logits, nxt, new_tokens, _scatter_rows(cache, new_sub, slots)
+        with jax.named_scope("scatter"):
+            new_tokens = tokens.at[slots].set(nxt)
+            new_cache = _scatter_rows(cache, new_sub, slots)
+        return logits, nxt, new_tokens, new_cache
 
     def _note_compile(self, bucket: int) -> None:
         """Runs while tracing (never on executable replay): count a compile."""
@@ -221,14 +224,18 @@ class DecodeRunner:
             return np.zeros(0, np.int32), tokens, cache
         _, nxt, new_tokens, new_cache = self._replay(params, cache, tokens,
                                                      slots)
-        return np.asarray(nxt)[:n], new_tokens, new_cache
+        with span("runner.readback", "serving", "runner"):
+            nxt = np.asarray(nxt)
+        return nxt[:n], new_tokens, new_cache
 
     def _replay(self, params, cache, tokens, slots):
         bucket = self.bucket_for(len(slots))
         compiled = self._ensure_compiled(bucket, params, cache, tokens)
         padded = list(slots) + [slots[-1]] * (bucket - len(slots))
-        return compiled(params, cache, tokens,
-                        jnp.asarray(padded, jnp.int32))
+        with span("runner.put", "serving", "runner"):
+            padded = jnp.asarray(padded, jnp.int32)
+        with span("runner.launch", "serving", "runner"):
+            return compiled(params, cache, tokens, padded)
 
     def stats(self) -> dict:
         return {"buckets": list(self.buckets),

@@ -7,14 +7,17 @@ no-overlap invariant — re-checked here with the independent rectangle
 checker from ``test_packing_invariants``, reconstructed purely from the
 exported JSON.
 """
+import gc
 import json
 import types
 
+import jax
 import pytest
 
 from repro.core import MemoryProfile, best_fit, make_profile
 from repro.core.arena import ArenaAllocator
 from repro.core.events import Block
+from repro.obs import trace as obs_trace
 from repro.obs import (ChromeTraceBuilder, ManualClock, TraceEvent, Tracer,
                        disable, enable, get_tracer, plan_rectangles,
                        use_tracer, validate_chrome_trace)
@@ -102,6 +105,94 @@ def test_step_stamp_and_span():
     assert ev.ph == "X" and ev.step == 7 and ev.track == "engine"
     assert ev.dur == pytest.approx(2000.0)
     assert ev.args["what"] == "x"
+
+
+def test_span_ids_and_parents_nest():
+    clk = ManualClock()
+    t = Tracer(clock=clk)
+    with t.span("outer", "serving", track="engine"):
+        t.instant("mark", "serving")
+        with t.span("inner", "serving", track="runner") as sp:
+            clk.advance(0.001)
+            sp.note(late=1)
+        with t.span("second", "serving"):
+            pass
+    t.instant("after", "serving")
+    by = {e.name: e for e in t.events()}
+    # spans are emitted as they close, innermost first
+    assert [e.name for e in t.events()] == ["mark", "inner", "second",
+                                            "outer", "after"]
+    outer, inner, second = by["outer"], by["inner"], by["second"]
+    assert outer.span_id and inner.span_id and second.span_id
+    assert len({outer.span_id, inner.span_id, second.span_id}) == 3
+    assert outer.parent_id == 0
+    assert inner.parent_id == second.parent_id == outer.span_id
+    assert by["mark"].parent_id == outer.span_id and by["mark"].span_id == 0
+    assert by["after"].parent_id == 0
+    assert inner.args == {"late": 1}
+    assert outer.ts <= inner.ts and \
+        inner.ts + inner.dur <= outer.ts + outer.dur
+
+
+def test_span_helper_without_tracer_is_the_shared_no_op():
+    assert get_tracer() is None
+    sp = obs_trace.span("step", "serving", "engine", step=1)
+    assert sp is obs_trace.NO_SPAN
+    with sp as inner:
+        inner.note(x=1)
+    mine = Tracer()
+    with use_tracer(mine):
+        with obs_trace.span("step", "serving", "engine", step=1):
+            pass
+    assert [(e.name, e.ph, e.args) for e in mine.events()] == \
+        [("step", "X", {"step": 1})]
+    with obs_trace.span("step", "serving"):
+        pass
+    assert len(mine.events()) == 1          # nothing after the tracer left
+
+
+def test_gc_spans_only_while_enabled():
+    t = enable(Tracer())
+    try:
+        gc.collect()
+    finally:
+        assert disable() is t
+    spans = [e for e in t.events() if e.name == "gc"]
+    assert spans and all(e.cat == "host" and e.ph == "X" for e in spans)
+    assert spans[-1].args["generation"] == 2
+    assert "collected" in spans[-1].args
+    n = len(t.events())
+    gc.collect()
+    assert len(t.events()) == n
+
+
+def test_backend_compile_span_while_enabled():
+    t = enable(Tracer())
+    try:
+        jax.jit(lambda x: x * 3 + 1).lower(
+            jax.ShapeDtypeStruct((7, 5), "float32")).compile()
+    finally:
+        disable()
+    (ev,) = [e for e in t.events() if e.name == "backend-compile"]
+    assert ev.cat == "host" and ev.ph == "X" and ev.dur > 0
+    assert ev.args["seconds"] == pytest.approx(ev.dur * 1e-6)
+    assert ev.ts + ev.dur <= t.now_us()
+
+
+def test_arena_replan_is_a_span_with_its_seconds():
+    arena = ArenaAllocator(make_profile([(64, 1, 3), (128, 2, 5)]))
+    t = Tracer()
+    with use_tracer(t):
+        arena.reset_iteration()
+        arena.free(arena.alloc(256))            # larger than profiled
+        arena.request_replan("decode-outrun")
+        arena.reset_iteration()                 # the boundary replan
+    (ev,) = [e for e in t.events() if e.name == "replan"]
+    assert ev.ph == "X" and ev.cat == "arena" and ev.span_id
+    assert ev.args["seconds"] == pytest.approx(arena.last_replan_s)
+    assert ev.args["seconds"] * 1e6 <= ev.dur
+    assert ev.args["new_peak"] == arena.plan.peak
+    assert ev.args["n_reopt"] == arena.n_reopt
 
 
 def test_global_tracer_install_and_restore():
@@ -236,6 +327,9 @@ def _check_plan_export(profile: MemoryProfile) -> None:
     rects = plan_rectangles(trace, "p")
     live = [b for b in profile.blocks if b.size > 0]
     assert len(rects) == len(live)
+    if not live:                # only empty blocks: nothing to draw or place
+        assert rects == [] and plan.peak == 0
+        return
 
     # reconstruction: blocks + offsets straight from the exported args
     blocks = [Block(bid=r["bid"], size=r["size"], start=r["start"],
