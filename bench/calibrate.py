@@ -40,6 +40,7 @@ def main(argv=None, *, root: str = ROOT, bench: str = BENCH,
     sp = spec_lib.Spec(root, bench)
     wl = sp.workload(args.workload)
     cfg_file = sp.config(wl["config"])
+    family = sp.family(cfg_file["family"])
     mix = sp.traffic(wl["traffic"])
     found = run_lib.find_chips(int(wl["chips"]), require_chip)
     if found is None:
@@ -59,10 +60,11 @@ def main(argv=None, *, root: str = ROOT, bench: str = BENCH,
     prog, ctl = [], []
     for seed in [int(s) for s in args.seeds.split(",")]:
         t0 = time.perf_counter()
-        run = cell_lib.run_cell(cfg_file, mix, sp.cell(args.workload),
-                                seed=seed, seconds=args.seconds,
-                                traced=False, peaks=peaks, t_proc0=t0)
-        got = check.compare(run, cfg_file, mix, seed, control=True)
+        run = cell_lib.run_cell(cfg_file, family, mix,
+                                sp.cell(args.workload), seed=seed,
+                                seconds=args.seconds, traced=False,
+                                peaks=peaks, t_proc0=t0)
+        got = check.compare(run, family, cfg_file, mix, seed, control=True)
         got["seed"] = seed
         got["failed"] = sum(1 for r in run.window_requests()
                             if r.first is None)

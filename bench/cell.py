@@ -11,18 +11,19 @@ the program's own tracer events when a run is traced.
 """
 from __future__ import annotations
 
+import bisect
 import gc
 import time
 from dataclasses import dataclass, field
-from typing import Optional
+from functools import cached_property
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 import traffic
-import weights as wlib
-from yardstick import Dims, Peaks
+from yardstick import Peaks
 
 CLOCK = time.perf_counter
 
@@ -40,10 +41,18 @@ class ReqRecord:
     preempted: int = 0
 
 
+class DecodeStep(NamedTuple):
+    """One decode call of the window, as a family's yardstick reads it."""
+    start: float
+    end: float
+    contexts: list              # each running row's context, in slot order
+    events: list                # the program's tracer events inside it
+
+
 @dataclass
 class RunRecord:
     """What a run leaves for the metric readers: plain host data only."""
-    dims: Dims
+    yardstick: object           # the family's, for the configuration run
     peaks: Peaks
     setup_s: float
     setup_stages: list          # (stage, seconds), in order
@@ -54,7 +63,7 @@ class RunRecord:
     steps: list                 # (start, end)
     decode_counter: tuple       # ((time_s, steps) at window start, at end)
     kv_held_bytes: int
-    live_kv: list               # (step end, live context tokens)
+    live_kv: list               # (step end, [context of each active request])
     tracer_events: list         # repro.obs.trace events (traced runs)
     tracer_offset: float        # host clock of tracer ts 0
     device_trace: Optional[dict]
@@ -65,6 +74,27 @@ class RunRecord:
 
     def in_window(self, t: float) -> bool:
         return self.window[0] <= t < self.window[1]
+
+    def window_prefills(self) -> list:
+        """(start, end, prompt_len) of the prefills inside the window."""
+        return [p for p in self.prefills
+                if self.in_window(p[0]) and self.in_window(p[1])]
+
+    @cached_property
+    def _events_by_time(self) -> tuple:
+        """Tracer events sorted by start, and their starts on the host
+        clock."""
+        evs = sorted(self.tracer_events, key=lambda ev: ev.ts)
+        return evs, [self.tracer_offset + ev.ts * 1e-6 for ev in evs]
+
+    def window_decodes(self) -> list:
+        """The decode calls inside the window, in order, each with the
+        program's tracer events that start inside it."""
+        evs, ts = self._events_by_time
+        return [DecodeStep(start, end, ctx, evs[bisect.bisect_left(ts, start):
+                                                bisect.bisect_left(ts, end)])
+                for start, end, ctx in self.decodes
+                if self.in_window(start) and self.in_window(end)]
 
     def window_requests(self) -> list:
         return [r for r in self.requests if r.phase == "window"]
@@ -116,34 +146,17 @@ class _CompileCount:
             self.n += 1
 
 
-def build_model(cfg_file: dict):
-    """The program's model, as the configuration file names it."""
+def build_model(cfg_file: dict, family):
+    """The program's model, as the configuration file names it (a list in
+    ``overrides`` is the program's tuple)."""
     from repro.configs import get_config
     from repro.models.transformer import Transformer
     prog = cfg_file["program"]
-    cfg = get_config(prog["arch"]).with_overrides(**prog.get("overrides", {}))
-    check_program_config(cfg, cfg_file)
+    over = {k: tuple(v) if isinstance(v, list) else v
+            for k, v in prog.get("overrides", {}).items()}
+    cfg = get_config(prog["arch"]).with_overrides(**over)
+    family.check_program(cfg, cfg_file)
     return Transformer(cfg)
-
-
-def check_program_config(cfg, c: dict) -> None:
-    """The program must run the sizes the file states (the reference reads
-    the file, so a drift between the two would compare different
-    models)."""
-    want = {"n_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
-            "n_heads": c["num_attention_heads"],
-            "n_kv_heads": c["num_key_value_heads"],
-            "d_ff": c["intermediate_size"], "vocab_size": c["vocab_size"],
-            "resolved_head_dim": Dims.from_config(c).head_dim,
-            "rope_theta": c["rope_theta"],
-            "tie_embeddings": c["tie_word_embeddings"],
-            "qkv_bias": c.get("qkv_bias", False), "dtype": c["torch_dtype"],
-            "block_pattern": ("attn",), "tail_pattern": (), "act": "swiglu",
-            "norm": "rmsnorm", "n_experts": 0}
-    bad = {k: (getattr(cfg, k), v) for k, v in want.items()
-           if getattr(cfg, k) != v}
-    if bad:
-        raise ValueError(f"program config differs from the file: {bad}")
 
 
 def _prefill_buckets(lo: int, hi: int, max_len: int) -> list[int]:
@@ -159,12 +172,13 @@ def _prefill_buckets(lo: int, hi: int, max_len: int) -> list[int]:
         b *= 2
 
 
-def run_cell(cfg_file: dict, mix: dict, cell: dict, *, seed: int,
+def run_cell(cfg_file: dict, family, mix: dict, cell: dict, *, seed: int,
              seconds: float, traced: bool, peaks: Peaks, t_proc0: float,
              trace_dir: Optional[str] = None, fault=None) -> RunRecord:
-    """Set up, serve the schedule, and return the record.  Every device
-    buffer of the program is dropped before this returns.  ``fault``, for
-    the harness's tests, is called with the warmed engine to break it."""
+    """Set up, serve the schedule, and return the record.  ``family`` is
+    the configuration's module of ``families/``.  Every device buffer of
+    the program is dropped before this returns.  ``fault``, for the
+    harness's tests, is called with the warmed engine to break it."""
     from repro.obs.trace import Tracer, disable, enable
     from repro.runtime.serve_lib import Request
     from repro.serving import ServeEngine
@@ -176,14 +190,10 @@ def run_cell(cfg_file: dict, mix: dict, cell: dict, *, seed: int,
         stages.append((name, CLOCK()))
 
     stage("imports")
-    dims = Dims.from_config(cfg_file)
+    yard = family.yardstick(cfg_file)
     eng_cfg = cfg_file["engine"]
-    model = build_model(cfg_file)
-    dtype = jnp.dtype(cfg_file["torch_dtype"])
-    params = wlib.program_params(seed, dims,
-                                 jax.eval_shape(model.init,
-                                                jax.random.PRNGKey(0)),
-                                 dtype)
+    model = build_model(cfg_file, family)
+    params = family.program_params(seed, cfg_file, model)
     jax.block_until_ready(params)
     stage("weights")
     rate = float(cell["rate_per_s"])
@@ -237,7 +247,7 @@ def run_cell(cfg_file: dict, mix: dict, cell: dict, *, seed: int,
                                               eng_cfg["max_len"])):
         engine.enqueue(GenRequest(
             rid=-1 - i, prompt=jnp.asarray(
-                rng.integers(0, dims.vocab, plen, dtype=np.int32)),
+                rng.integers(0, yard.vocab, plen, dtype=np.int32)),
             gen_len=2))
     while not engine.sched.idle:
         engine.step()
@@ -256,7 +266,7 @@ def run_cell(cfg_file: dict, mix: dict, cell: dict, *, seed: int,
     rec.step_tokens.clear()
     rec.preempted.clear()
 
-    prompts = traffic.prompt_tokens(reqs, dims.vocab, seed)
+    prompts = traffic.prompt_tokens(reqs, yard.vocab, seed)
     prompt_dev = {rid: jnp.asarray(t) for rid, t in prompts.items()}
     jax.block_until_ready(list(prompt_dev.values()))
     if fault is not None:
@@ -346,8 +356,8 @@ def run_cell(cfg_file: dict, mix: dict, cell: dict, *, seed: int,
                     r.first = t1
                     waiting.discard(rid)
         rec.step_tokens.clear()
-        live_kv.append((t1, sum(sr.prompt_len + len(sr.out)
-                                for sr in engine.sched.active.values())))
+        live_kv.append((t1, [sr.prompt_len + len(sr.out)
+                             for sr in engine.sched.active.values()]))
     if counter1 is None:
         counter1 = (engine.decode_time_s, engine.decode_steps)
         compiles1 = compiles.n
@@ -373,7 +383,7 @@ def run_cell(cfg_file: dict, mix: dict, cell: dict, *, seed: int,
         import tracing
         device_trace = tracing.reduce_dir(trace_dir)
     return RunRecord(
-        dims=dims, peaks=peaks, setup_s=setup_s, window=(w0, w1),
+        yardstick=yard, peaks=peaks, setup_s=setup_s, window=(w0, w1),
         setup_stages=setup_stages,
         requests=records, prefills=prefills, decodes=decodes, steps=steps,
         decode_counter=(counter0 or counter1, counter1),

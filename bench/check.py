@@ -17,7 +17,7 @@ import random
 
 import numpy as np
 
-from reference import Reference, served_gaps
+from reference import served_gaps
 
 
 def sample(run, mix: dict, seed: int) -> list:
@@ -40,29 +40,23 @@ def sample(run, mix: dict, seed: int) -> list:
     return [r.rid for r in out]
 
 
-def reference_for(cfg_file: dict, dims, seed: int, quant: str = "none"):
-    return Reference(dims=dims, theta=float(cfg_file["rope_theta"]),
-                     eps=float(cfg_file["rms_norm_eps"]),
-                     seed=seed,
-                     seq_len=cfg_file["engine"]["max_len"],
-                     rows=cfg_file["check"]["rows"], quant=quant)
-
-
-def compare(run, cfg_file: dict, mix: dict, seed: int,
+def compare(run, family, cfg_file: dict, mix: dict, seed: int,
             control: bool = False) -> dict:
-    """The numbers compared, with what was checked.  ``control`` also
-    reads the fp8 control at the same positions."""
+    """The numbers compared, with what was checked, against ``family``'s
+    reference.  ``control`` also reads the fp8 control at the same
+    positions."""
     rids = sample(run, mix, seed)
     out = {"requests": len(rids), "tokens": 0}
     if not rids:
         return out
     bad_ids = [rid for rid in rids
-               if not all(0 <= t < run.dims.vocab for t in run.served[rid])]
+               if not all(0 <= t < run.yardstick.vocab
+                          for t in run.served[rid])]
     out["out_of_vocab"] = len(bad_ids)
     reqs = [(np.asarray(run.prompts[rid]), np.asarray(run.served[rid]))
             for rid in rids]
-    ref = reference_for(cfg_file, run.dims, seed)
-    ctl = reference_for(cfg_file, run.dims, seed, "fp8") if control else None
+    ref = family.reference(cfg_file, seed)
+    ctl = family.reference(cfg_file, seed, "fp8") if control else None
     out.update(served_gaps(ref, reqs, mix["output"]["max"], ctl))
     return out
 

@@ -1,4 +1,5 @@
-"""Share of the traced window in which no operation ran on the device."""
+"""Share of the traced window, as far as the trace holds the device's
+operations, in which no operation ran on the device."""
 
 
 def read(run):

@@ -1,10 +1,11 @@
 """KV bytes the engine holds on the device over the most KV that running
-requests needed at once in the window (their context tokens times the KV
-bytes of one token)."""
+requests needed at once in the window (the family's yardstick on their
+contexts)."""
 
 
 def read(run):
-    live = [n for t, n in run.live_kv if run.in_window(t)]
+    live = [run.yardstick.kv_bytes(ctx) for t, ctx in run.live_kv
+            if run.in_window(t)]
     if not live or not max(live):
         return None
-    return run.kv_held_bytes / (max(live) * run.dims.kv_bytes_per_token())
+    return run.kv_held_bytes / max(live)

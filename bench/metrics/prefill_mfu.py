@@ -1,13 +1,14 @@
-"""Useful FLOPs of the prefills in the traced window (causal attention
-counted, padding not) over the prefill program's device time times the
-chip's bf16 peak, in percent."""
+"""Useful FLOPs of the prefills whose programs the trace holds (causal
+attention counted, padding not) over the prefill program's device time
+times the chip's bf16 peak, in percent."""
+from tracing import recorded
 
 
 def read(run):
     dt = run.device_trace
     if not dt or not dt["program_s"].get("prefill"):
         return None
-    flops = sum(run.dims.prefill_flops(n) for start, end, n in run.prefills
-                if run.in_window(start) and run.in_window(end))
+    flops = sum(run.yardstick.prefill_flops(n) for _, _, n in
+                recorded(run.window_prefills(), dt, "prefill"))
     return 100.0 * flops / (dt["program_s"]["prefill"] *
                             run.peaks.bf16_flops)

@@ -75,6 +75,7 @@ def main(argv=None, *, root: str = ROOT, bench: str = BENCH,
     sp = spec_lib.Spec(root, bench)
     wl = sp.workload(args.workload)
     cfg_file = sp.config(wl["config"])
+    family = sp.family(cfg_file["family"])
     mix = sp.traffic(wl["traffic"])
     cell_load = sp.cell(args.workload)
 
@@ -101,7 +102,7 @@ def main(argv=None, *, root: str = ROOT, bench: str = BENCH,
         trace_dir = os.path.join(root, "bench_out",
                                  f"trace-{args.workload}-{args.seed}")
         shutil.rmtree(trace_dir, ignore_errors=True)
-    run = cell_lib.run_cell(cfg_file, mix, cell_load, seed=args.seed,
+    run = cell_lib.run_cell(cfg_file, family, mix, cell_load, seed=args.seed,
                             seconds=args.seconds, traced=bool(args.trace),
                             peaks=peaks, t_proc0=t_proc0,
                             trace_dir=trace_dir, fault=fault)
@@ -119,7 +120,7 @@ def main(argv=None, *, root: str = ROOT, bench: str = BENCH,
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
     t_chk = time.perf_counter()
-    got = check.compare(run, cfg_file, mix, args.seed)
+    got = check.compare(run, family, cfg_file, mix, args.seed)
     correct, compared = check.judge(got, cfg_file["check"]["limits"])
     log(f"check: {got} in {time.perf_counter() - t_chk:.1f} s")
 
@@ -132,7 +133,12 @@ def main(argv=None, *, root: str = ROOT, bench: str = BENCH,
     dt = run.device_trace
     if args.trace and dt:
         device["busy_s"] = dt["busy_s"]
-        device["window_s"] = dt["window_s"]
+        device["window_s"] = dt["window_s"]     # the part the trace holds
+        device["span_s"] = dt["span_s"]         # the whole window span
+        log(f"trace holds {dt['window_s']:.3f} s of the {dt['span_s']:.3f} s "
+            f"window: programs held on every device {dt['held_n']} of "
+            f"{len(run.window_decodes())} decode and "
+            f"{len(run.window_prefills())} prefill calls")
         result["breakdown"] = {"device_ops": [list(x) for x in
                                               dt["device_ops"]],
                                "idle_gaps": [list(x) for x in

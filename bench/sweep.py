@@ -62,11 +62,12 @@ def main(argv=None) -> int:
     from stats import percentile
     from yardstick import peaks_for
     cfg, mix = sp.config(wl["config"]), sp.traffic(wl["traffic"])
+    family = sp.family(cfg["family"])
     pairs = [(int(s), float(r)) for s in args.seeds.split(",")
              for r in args.rates.split(",")]
     for seed, rate in pairs:
         t0 = time.perf_counter()
-        run = cell_lib.run_cell(cfg, mix, {"rate_per_s": rate},
+        run = cell_lib.run_cell(cfg, family, mix, {"rate_per_s": rate},
                                 seed=seed, seconds=args.seconds,
                                 traced=False, peaks=peaks_for(found[1]),
                                 t_proc0=t0)

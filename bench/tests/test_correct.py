@@ -26,11 +26,11 @@ def root(tmp_path_factory):
     return tiny.make_root(str(tmp_path_factory.mktemp("tiny")))
 
 
-def run_cell(root, fault=None, seed=SEED):
+def run_cell(root, fault=None, seed=SEED, workload="tiny.chat"):
     import run as run_lib
     buf = io.StringIO()
     with redirect_stdout(buf):
-        rc = run_lib.main(["--workload", "tiny.chat", "--seed", str(seed),
+        rc = run_lib.main(["--workload", workload, "--seed", str(seed),
                            "--seconds", "2", "--trace", "0"],
                           root=root, bench=f"{root}/bench",
                           require_chip=False, fault=fault)
@@ -96,9 +96,11 @@ def test_fp8_control_fails_the_limit(root):
     sp = spec_lib.Spec(root, f"{root}/bench")
     cfg, mix = sp.config("tiny"), sp.traffic("tiny-chat")
     jax.config.update("jax_enable_compilation_cache", False)
-    rec = drive(cfg, mix, sp.cell("tiny.chat"), seed=SEED + 1, seconds=2.0,
-                traced=False, peaks=peaks_for("TPU v5 lite"), t_proc0=0.0)
-    got = check.compare(rec, cfg, mix, SEED + 1, control=True)
+    family = sp.family(cfg["family"])
+    rec = drive(cfg, family, mix, sp.cell("tiny.chat"), seed=SEED + 1,
+                seconds=2.0, traced=False, peaks=peaks_for("TPU v5 lite"),
+                t_proc0=0.0)
+    got = check.compare(rec, family, cfg, mix, SEED + 1, control=True)
     limit = cfg["check"]["limits"]["logit_gap_max"]
     print(got, file=sys.stderr)
     assert got["logit_gap_max"] <= limit < got["control_gap_max"]

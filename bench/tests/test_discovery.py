@@ -78,3 +78,19 @@ def test_every_listed_metric_has_a_reader():
         sp.traffic(w["traffic"])
         sp.config(w["config"])
     assert root
+
+
+def test_family_is_this_roots_file(tmp_path):
+    """Each root's ``families/<name>.py`` is its own: a family file that
+    differs in another root is read from there, and the importable one is
+    the ``families`` package's module."""
+    root = tiny.make_root(str(tmp_path))
+    b = os.path.join(root, "bench")
+    path = os.path.join(b, "families", "dense_gqa.py")
+    with open(path, "a") as f:
+        f.write("\nPLANTED = 1\n")
+    here = spec_lib.Spec(tiny.REPO).family("dense_gqa")
+    there = spec_lib.Spec(root, b).family("dense_gqa")
+    assert there.__file__ == path and there.PLANTED == 1
+    assert not hasattr(here, "PLANTED")
+    assert spec_lib.Spec(tiny.REPO).family("dense_gqa") is here

@@ -9,7 +9,8 @@ import pytest
 
 from cell import ReqRecord, RunRecord
 from repro.obs.trace import TraceEvent
-from yardstick import Dims, peaks_for
+from families.dense_gqa import Dims
+from yardstick import peaks_for
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NEW = ("decode_host_gap_ms", "runner_launch_ms", "engine_host_ms",
@@ -31,7 +32,7 @@ def make_run(events, device_trace=None, requests=()):
                 d_ff=16, vocab=100, padded_vocab=256, tied=True,
                 qkv_bias=False)
     return RunRecord(
-        dims=dims, peaks=peaks_for("TPU v5 lite"), setup_s=5.0,
+        yardstick=dims, peaks=peaks_for("TPU v5 lite"), setup_s=5.0,
         setup_stages=[], window=(10.0, 20.0), requests=list(requests),
         prefills=[], decodes=[], steps=[(19.0, 21.0)],
         decode_counter=((0.0, 0), (1.0, 100)), kv_held_bytes=0, live_kv=[],
@@ -174,3 +175,20 @@ def test_a_traced_tiny_run_reports_the_span_metrics(tmp_path):
         assert got[name]["value"] >= 0.0, name
     assert got["runner_launch_ms"]["value"] > 0.0
     assert 0.0 < got["first_token_hold_p50_ms"]["value"] < 1e3
+
+
+def test_a_decode_step_carries_the_program_events_inside_it():
+    """What a family's yardstick gets of a decode step: its contexts and
+    the tracer events that start inside it, on the host clock."""
+    run = make_run(two_steps_in_window())
+    run.decodes = [(9.4, 9.6, [3]),                 # before the window
+                   (11.0 + 0.0025, 11.0 + 0.02, [5, 9]),
+                   (12.0 + 0.0025, 12.0 + 0.02, [6])]
+    got = run.window_decodes()
+    assert [s.contexts for s in got] == [[5, 9], [6]]
+    # the decode span, its runner children and the first-token stamp
+    # start inside each step; the step's own span and prefill do not
+    names = ["decode", "first-token", "runner.launch", "runner.put",
+             "runner.readback"]
+    assert [sorted(ev.name for ev in s.events) for s in got] == [
+        sorted(names + ["gc"]), names]

@@ -4,6 +4,7 @@ import os
 from collections import Counter
 
 import numpy as np
+import pytest
 
 import traffic
 
@@ -26,8 +27,9 @@ def test_same_seed_same_schedule():
 
 
 def test_seeds_reorder_the_same_work():
-    a = traffic.schedule(MIX, 20.0, 30.0, 1)
-    b = traffic.schedule(MIX, 20.0, 30.0, 2)
+    mix = {**MIX, "order": "shuffled"}
+    a = traffic.schedule(mix, 20.0, 30.0, 1)
+    b = traffic.schedule(mix, 20.0, 30.0, 2)
     assert _key(a) != _key(b)
     for phase in traffic.PHASES:
         pa = [r for r in a if r.phase == phase]
@@ -37,6 +39,23 @@ def test_seeds_reorder_the_same_work():
             "drain": MIX["drain_s"]}[phase])
         assert Counter((r.prompt_len, r.gen_len) for r in pa) == \
             Counter((r.prompt_len, r.gen_len) for r in pb)
+
+
+def test_fixed_order_is_one_schedule_for_every_seed():
+    assert MIX["order"] == "fixed"
+    a = traffic.schedule(MIX, 20.0, 30.0, 1)
+    assert _key(a) == _key(traffic.schedule(MIX, 20.0, 30.0, 2 ** 31 + 9))
+    shuffled = traffic.schedule({**MIX, "order": "shuffled"}, 20.0, 30.0, 1)
+    assert Counter((r.prompt_len, r.gen_len, r.phase) for r in a) == \
+        Counter((r.prompt_len, r.gen_len, r.phase) for r in shuffled)
+    ta = traffic.prompt_tokens(a, 151936, 1)
+    tb = traffic.prompt_tokens(a, 151936, 2)
+    assert not all(np.array_equal(ta[k], tb[k]) for k in ta)
+
+
+def test_unknown_order_is_refused():
+    with pytest.raises(ValueError, match="order"):
+        traffic.schedule({**MIX, "order": "sorted"}, 20.0, 30.0, 1)
 
 
 def test_phases_and_bounds():

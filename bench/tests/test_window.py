@@ -7,7 +7,8 @@ import pytest
 
 from cell import ReqRecord, RunRecord
 from stats import percentile
-from yardstick import Dims, peaks_for
+from families.dense_gqa import Dims
+from yardstick import peaks_for
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -25,8 +26,9 @@ def make_run(requests, prefills=(), window=(10.0, 20.0), steps=None):
                 d_ff=16, vocab=100, padded_vocab=256, tied=True,
                 qkv_bias=False)
     return RunRecord(
-        dims=dims, peaks=peaks_for("TPU v5 lite"), setup_s=5.0, setup_stages=[],
-        window=window, requests=requests, prefills=list(prefills),
+        yardstick=dims, peaks=peaks_for("TPU v5 lite"), setup_s=5.0,
+        setup_stages=[], window=window, requests=requests,
+        prefills=list(prefills),
         decodes=[], steps=steps or [(window[1] - 1, window[1] + 3)],
         decode_counter=((0.0, 0), (1.0, 100)), kv_held_bytes=0, live_kv=[],
         tracer_events=[], tracer_offset=0.0, device_trace=None,

@@ -14,7 +14,8 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(BENCH)
 
 TINY_CONFIG = {
-    "name": "tiny", "source": "test", "hidden_size": 64,
+    "name": "tiny", "source": "test", "family": "dense_gqa",
+    "hidden_size": 64,
     "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2,
     "vocab_size": 1000, "hidden_act": "silu", "rms_norm_eps": 1e-06,
@@ -28,7 +29,7 @@ TINY_CONFIG = {
 }
 
 TINY_TRAFFIC = {
-    "arrival": "poisson",
+    "arrival": "poisson", "order": "fixed",
     "prompt": {"median": 16, "sigma": 0.6, "min": 4, "max": 60},
     "output": {"median": 8, "sigma": 0.6, "min": 2, "max": 24},
     "shape_seed": 7, "warmup_s": 0.5, "drain_s": 10.0,

@@ -18,6 +18,14 @@ the profiler's own clock.
 The profiler puts host and device on one clock only to about a
 millisecond (a recorded v5e trace had the device ~1.2 ms behind), so a
 gap's host span is right for gaps longer than that.
+
+The profiler stops recording a device's operations after some millions of
+events (~6.1 M on a v5e) while the host spans go on.  A trace is cut where
+a device's last recorded operation ends and an engine step (``bench.step``)
+starts after it inside the window: that step ran programs the trace does
+not hold.  Every number is then read from the window's start to that end,
+the part the trace holds (``window_s``; the whole span is ``span_s``), and
+``recorded`` picks the host's calls whose programs it holds.
 """
 from __future__ import annotations
 
@@ -31,6 +39,7 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
+STEP_SPAN = "bench.step"
 
 
 def program_kind(module_name: str) -> str:
@@ -105,13 +114,35 @@ def _op_label(name: str, mods, mstarts, t) -> str:
     return f"{kind}/{op}"
 
 
+def recorded_end(ex: dict, hi: int) -> int:
+    """Where the trace stops holding the window that ends at ``hi``: the
+    end of the last recorded operation of the device whose recording
+    stopped first, where an engine step starts after it before ``hi``;
+    else ``hi``."""
+    end = min(max(e for _, e, _ in evs) for evs in ex["ops"].values())
+    if end < hi and any(name == STEP_SPAN and end < s < hi
+                        for s, _, name in ex["spans"]):
+        return end
+    return hi
+
+
+def recorded(calls: list, dt: dict, kind: str) -> list:
+    """The first of ``calls`` (the window's calls of one kind, in order), as
+    many as every device holds programs of ``kind``: the calls whose device
+    time is ``dt["program_s"][kind]``."""
+    return calls[:dt["held_n"].get(kind, 0)]
+
+
 def reduce(ex: dict, top: int = 10) -> Optional[dict]:
-    """Numbers of the window span; None if the trace holds no window or no
-    device operation."""
+    """Numbers of the window span, as far as the trace holds it; None if
+    the trace holds no window or no device operation."""
     win = [s for s in ex["spans"] if s[2] == WINDOW_SPAN]
     if not win or not ex["ops"]:
         return None
-    lo, hi = win[0][0], win[0][1]
+    lo, span_hi = win[0][0], win[0][1]
+    hi = recorded_end(ex, span_hi)
+    if hi <= lo:
+        return None
     window_s = (hi - lo) * 1e-9
     busy, gaps, op_time = [], [], defaultdict(float)
     prog_time, prog_count = defaultdict(float), defaultdict(int)
@@ -130,12 +161,17 @@ def reduce(ex: dict, top: int = 10) -> Optional[dict]:
             if e > lo and s < hi:
                 op_time[_op_label(name, mods, mstarts, s)] += \
                     (min(e, hi) - max(s, lo)) * 1e-9
+    dev_counts = []
     for dev, evs in ex["modules"].items():
+        n = defaultdict(int)
         for s, e, name in evs:
             if s >= lo and e <= hi:
                 k = program_kind(name)
                 prog_time[k] += (e - s) * 1e-9
-                prog_count[k] += 1
+                n[k] += 1
+        dev_counts.append(n)
+        for k, c in n.items():
+            prog_count[k] += c
     spans = sorted(s for s in ex["spans"] if s[1] > lo and s[0] < hi)
     starts = [s[0] for s in spans]
     idle_by = defaultdict(float)
@@ -145,10 +181,12 @@ def reduce(ex: dict, top: int = 10) -> Optional[dict]:
     busy_s = sum(busy) / n_dev
     return {
         "window_s": window_s,
+        "span_s": (span_hi - lo) * 1e-9,
         "busy_s": busy_s,
         "idle_pct": 100.0 * (1.0 - busy_s / window_s),
         "program_s": dict(prog_time),
         "program_n": dict(prog_count),
+        "held_n": {k: min(n[k] for n in dev_counts) for k in prog_count},
         "device_ops": sorted(op_time.items(), key=lambda kv: -kv[1])[:top],
         "idle_gaps": sorted(((k, v / n_dev) for k, v in idle_by.items()),
                             key=lambda kv: -kv[1])[:top],

@@ -6,10 +6,11 @@ the drain, the most the run waits after the window for every request due
 in it to get its first token (arrivals go on meanwhile, unmeasured).  A
 cell's own file (``cells/<workload>.json``) gives its fixed rate.
 
-Every seed gets the same work in another order.  The sizes and the gaps
-between arrivals are drawn once from the mix's ``shape_seed``; ``--seed``
-shuffles both and draws the prompt tokens.  So runs differ in which request
-comes when, not in how much there is to do.
+Every seed gets the same work.  The sizes and the gaps between arrivals
+are drawn once from the mix's ``shape_seed``; ``--seed`` draws the prompt
+tokens and, where the mix's ``order`` is ``"shuffled"``, shuffles sizes and
+gaps.  With ``"fixed"`` every seed replays one schedule, so that a tail set
+by how many long requests overlap does not change from seed to seed.
 
 The length sampler is the seeded lognormal of ``repro.serving.loadgen``
 (median, sigma, clipped), with arrivals on the wall clock in seconds.
@@ -61,6 +62,8 @@ def schedule(mix: dict, rate: float, window_s: float, seed: int) -> list[Req]:
     time.  The window starts at ``mix["warmup_s"]``."""
     if mix["arrival"] != "poisson":
         raise ValueError(f"unknown arrival process {mix['arrival']!r}")
+    if mix["order"] not in ("fixed", "shuffled"):
+        raise ValueError(f"unknown order {mix['order']!r}")
     spans = {"warmup": float(mix["warmup_s"]), "window": float(window_s),
              "drain": float(mix["drain_s"])}
     base = random.Random(mix["shape_seed"])
@@ -71,8 +74,9 @@ def schedule(mix: dict, rate: float, window_s: float, seed: int) -> list[Req]:
         n = int(round(rate * span))
         gaps = _poisson_times(base, rate, span, n)
         sizes = _sizes(base, mix, n)
-        shuffle.shuffle(gaps)
-        shuffle.shuffle(sizes)
+        if mix["order"] == "shuffled":
+            shuffle.shuffle(gaps)
+            shuffle.shuffle(sizes)
         t = t0
         for g, (p, o) in zip(gaps, sizes):
             t += g
